@@ -39,6 +39,17 @@ from repro.nn.module import logical
 NEG_INF = -1e30
 
 
+def _per_head(x, w, cd):
+    """Per-head projection of gathered rows: (B, H, n, a) x (H, a, c) ->
+    (B, H, n, c) in ``cd`` with fp32 accumulation.  The head axis is the
+    contraction's batch dimension and leads both operands — the canonical
+    batched-matmul layout, which every backend runs for bf16 inputs with an
+    fp32 result."""
+    y = jnp.einsum("hbna,hac->hbnc", x.astype(cd).swapaxes(0, 1),
+                   w.astype(cd), preferred_element_type=jnp.float32)
+    return y.swapaxes(0, 1).astype(cd)
+
+
 @dataclasses.dataclass(frozen=True)
 class MoSAAttention:
     d_model: int
@@ -145,12 +156,9 @@ class MoSAAttention:
         xs = ad_checkpoint.checkpoint_name(xs, "mosa_gather")
         r = ad_checkpoint.checkpoint_name(r, "mosa_router")
 
-        q = jnp.einsum("bnkh,nhd->bnkd", xs, params["wq"].astype(cd),
-                       preferred_element_type=jnp.float32).astype(cd)
-        kk = jnp.einsum("bnkh,nhd->bnkd", xs, params["wk"].astype(cd),
-                        preferred_element_type=jnp.float32).astype(cd)
-        v = jnp.einsum("bnkh,nhd->bnkd", xs, params["wv"].astype(cd),
-                       preferred_element_type=jnp.float32).astype(cd)
+        q = _per_head(xs, params["wq"], cd)
+        kk = _per_head(xs, params["wk"], cd)
+        v = _per_head(xs, params["wv"], cd)
         q = rope_lib.apply_rope(q, pos_sel, self.rope_theta, self.rotary_frac)
         kk = rope_lib.apply_rope(kk, pos_sel, self.rope_theta, self.rotary_frac)
 
@@ -168,9 +176,7 @@ class MoSAAttention:
 
         # Per-head output projection, then scatter-add to original positions
         # (vmap'd over batch — see gather note above).
-        y_heads = jnp.einsum("bnkd,ndh->bnkh", att.astype(cd),
-                             params["wo"].astype(cd),
-                             preferred_element_type=jnp.float32).astype(cd)
+        y_heads = _per_head(att, params["wo"], cd)
 
         def scatter_one(yh, ib):
             return jnp.zeros((T, h), cd).at[ib.reshape(-1)].add(
@@ -240,12 +246,9 @@ class MoSAAttention:
         xs = ad_checkpoint.checkpoint_name(xs, "mosa_gather")
         rblk = ad_checkpoint.checkpoint_name(rblk, "mosa_router")
 
-        q = jnp.einsum("bnkh,nhd->bnkd", xs, params["wq"].astype(cd),
-                       preferred_element_type=jnp.float32).astype(cd)
-        kk = jnp.einsum("bnkh,nhd->bnkd", xs, params["wk"].astype(cd),
-                        preferred_element_type=jnp.float32).astype(cd)
-        v = jnp.einsum("bnkh,nhd->bnkd", xs, params["wv"].astype(cd),
-                       preferred_element_type=jnp.float32).astype(cd)
+        q = _per_head(xs, params["wq"], cd)
+        kk = _per_head(xs, params["wk"], cd)
+        v = _per_head(xs, params["wv"], cd)
         q = rope_lib.apply_rope(q, pos_rope, self.rope_theta, self.rotary_frac)
         kk = rope_lib.apply_rope(kk, pos_rope, self.rope_theta,
                                  self.rotary_frac)
@@ -267,9 +270,7 @@ class MoSAAttention:
             att = self._einsum_block_attention(q, kk, v, pos, r_tok,
                                                seg=seg_sel)
 
-        y_heads = jnp.einsum("bnkd,ndh->bnkh", att.astype(cd),
-                             params["wo"].astype(cd),
-                             preferred_element_type=jnp.float32).astype(cd)
+        y_heads = _per_head(att, params["wo"], cd)
 
         tgt = jnp.where(pos >= 0, pos, T)             # T -> dropped
 
@@ -374,11 +375,9 @@ class MoSAAttention:
             scores = jnp.where(valid[:, None, :], scores, -1.0)
         r, idx = select_topk(scores, k, c.force_first_token)
         xs = jax.vmap(lambda xb, ib: xb[ib])(x.astype(cd), idx)
-        kk = jnp.einsum("bnkh,nhd->bnkd", xs, params["wk"].astype(cd),
-                        preferred_element_type=jnp.float32).astype(cd)
+        kk = _per_head(xs, params["wk"], cd)
         kk = rope_lib.apply_rope(kk, idx, self.rope_theta, self.rotary_frac)
-        v = jnp.einsum("bnkh,nhd->bnkd", xs, params["wv"].astype(cd),
-                       preferred_element_type=jnp.float32).astype(cd)
+        v = _per_head(xs, params["wv"], cd)
         if valid is not None:
             sel_ok = r > 0.0
             r = jnp.where(sel_ok, r, -jnp.inf)
@@ -613,9 +612,7 @@ class MoSAAttention:
                          preferred_element_type=jnp.float32)
         r_q = jnp.where(is_suffix, jnp.maximum(r_st, 0.0), 0.0)
         att = att * r_q[..., None]
-        y_heads = jnp.einsum("bnkd,ndh->bnkh", att.astype(cd),
-                             params["wo"].astype(cd),
-                             preferred_element_type=jnp.float32).astype(cd)
+        y_heads = _per_head(att, params["wo"], cd)
 
         tgt = jnp.where(is_suffix, t_j, T)          # T -> dropped
 
@@ -829,9 +826,7 @@ class MoSAAttention:
                                  (B, H, P, bs)).reshape(B, H, P * bs)
         r_q = jnp.where(is_suffix, jnp.maximum(r_tok, 0.0), 0.0)
         att = att * r_q[..., None]
-        y_heads = jnp.einsum("bnkd,ndh->bnkh", att.astype(cd),
-                             params["wo"].astype(cd),
-                             preferred_element_type=jnp.float32).astype(cd)
+        y_heads = _per_head(att, params["wo"], cd)
         tgt = jnp.where(is_suffix, t_j, T)                      # T -> dropped
 
         def scatter_one(yh, tb):

@@ -98,6 +98,22 @@ def resolve(shape, roles) -> Optional[P]:
     return P(*out)
 
 
+def per_shard(fn, *args):
+    """``fn(*args)`` run on each shard of the ambient mesh (``shard_map``):
+    every argument and the one output split on their leading (batch, head)
+    dims over the ``dp`` and ``tp`` axes as ``resolve`` maps them for
+    ``args[0]``, all other dims whole.  For computations independent
+    across those dims that the SPMD partitioner cannot split itself, such
+    as a Pallas kernel.  A plain call outside a context or when nothing
+    resolves."""
+    spec = resolve(args[0].shape[:2], ("dp", "tp"))
+    if spec is None:
+        return fn(*args)
+    return jax.shard_map(fn, mesh=_HINTS.get()["mesh"],
+                         in_specs=(spec,) * len(args), out_specs=spec,
+                         check_vma=False)(*args)
+
+
 def constrain(x, roles):
     """``with_sharding_constraint`` under the ambient hints; identity when
     no context is active or nothing resolves (divisibility fallback)."""

@@ -21,7 +21,7 @@ from functools import partial
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 
@@ -58,7 +58,7 @@ def pipeline_forward(stage_fn, stage_params, x, *, mesh, n_microbatches: int,
     mb = B // n_microbatches
 
     @partial(shard_map, mesh=mesh, in_specs=(P(axis), P()), out_specs=P(),
-             check_rep=False)
+             check_vma=False)
     def run(params, xfull):
         local = jax.tree.map(lambda p: p[0], params)   # this device's stage
         stage = jax.lax.axis_index(axis)

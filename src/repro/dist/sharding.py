@@ -17,40 +17,23 @@ Two rule sets:
   * ``"fsdp_tp"`` — ``"tp"`` plus ZeRO/FSDP-style sharding of the ``embed``
                     axis over the data-parallel axes.
 
-Also hosts the jax-version compat shims (``AxisType``, ``make_mesh``) so the
-rest of the codebase never touches ``jax.sharding`` feature-detection.
+Also hosts ``make_mesh``: the program's meshes use ``Auto`` axes, which
+sharding constraints (``repro.dist.hints``) require; bare ``jax.make_mesh``
+defaults to ``Explicit`` axes.
 """
 
 from __future__ import annotations
 
-import inspect
 from typing import Any, Mapping, Optional, Sequence
 
 import jax
-from jax.sharding import Mesh, NamedSharding, PartitionSpec
-
-try:  # jax >= 0.5 (explicit-sharding axis types)
-    from jax.sharding import AxisType
-except ImportError:  # pragma: no cover - depends on installed jax
-    class AxisType:
-        """Stand-in for ``jax.sharding.AxisType`` on older jax releases."""
-
-        Auto = "auto"
-        Explicit = "explicit"
-        Manual = "manual"
+from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec
 
 from repro.core import kv_cache as _kvc
 from repro.nn.module import (LogicalSpec, init_shapes, logical,  # noqa: F401
                              named_shardings, resolve_spec, resolve_specs)
 
 P = PartitionSpec
-
-# Sharding-invariant RNG: with the legacy (non-partitionable) threefry that
-# older jax defaults to, jit with sharded out_shardings generates DIFFERENT
-# random values than the same program unsharded — sharded init would diverge
-# from single-device init.  Partitionable threefry makes random bits a pure
-# function of (key, position), independent of the mesh.
-jax.config.update("jax_threefry_partitionable", True)
 
 # Data-parallel mesh axes, outermost first; tensor-parallel axis name.
 DP_AXES = ("pod", "data")
@@ -74,16 +57,11 @@ RULE_SETS: Mapping[str, Mapping[str, Any]] = {
 }
 
 
-# --------------------------------------------------------------- mesh compat
+# ---------------------------------------------------------------------- mesh
 def make_mesh(shape: Sequence[int], axes: Sequence[str]) -> Mesh:
-    """``jax.make_mesh`` with ``axis_types=Auto`` where the jax supports it."""
-    kwargs = {}
-    try:
-        if "axis_types" in inspect.signature(jax.make_mesh).parameters:
-            kwargs["axis_types"] = (AxisType.Auto,) * len(shape)
-    except (TypeError, ValueError):  # pragma: no cover
-        pass
-    return jax.make_mesh(tuple(shape), tuple(axes), **kwargs)
+    """``jax.make_mesh`` with ``Auto`` axes (see the module docstring)."""
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(AxisType.Auto,) * len(shape))
 
 
 # ------------------------------------------------------------- axis fitting

@@ -15,17 +15,21 @@ _EXPORTS = {
     "mosa_attention_bwd_pallas": "mosa_backward",
     "mosa_attention_trainable": "mosa_vjp",
     "mosa_block_attention": "ops",
-    "mosa_block_attention_pallas": "mosa_block",
-    "mosa_block_attention_fwd_res": "mosa_block",
-    "mosa_block_attention_bwd_pallas": "mosa_block",
-    "mosa_block_attention_trainable": "mosa_block",
     "flash_attention_pallas": "flash_attention",
     "mosa_attention_ref": "ref",
     "mosa_block_attention_ref": "ref",
     "flash_attention_ref": "ref",
 }
 
-__all__ = list(_EXPORTS)
+__all__ = list(_EXPORTS) + ["interpret_default"]
+
+
+def interpret_default() -> bool:
+    """The one platform switch for every Pallas kernel in the repo: kernels
+    lower natively on a TPU backend and run through the Pallas interpreter
+    (or, for the paged serving kernels, the gather reference) elsewhere."""
+    import jax
+    return jax.default_backend() != "tpu"
 
 
 def __getattr__(name):

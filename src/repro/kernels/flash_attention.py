@@ -1,11 +1,12 @@
 """Pallas TPU kernel: causal (optionally sliding-window) flash attention, GQA.
 
 Serves the dense / local heads of the hybrid layer.  Standard flash-v2
-streaming softmax with BlockSpec VMEM tiling:
-
-  grid = (B, Hq, Tq // block_q); KV streamed in ``block_k`` tiles with the
-  block range cut to [lo, hi) by causality (and the sliding window), so the
-  work per query block is O(min(q_end, window) ) rather than O(Tk).
+streaming softmax with BlockSpec VMEM tiling: the grid's innermost
+``arbitrary`` axis streams KV blocks of ``block_k`` through VMEM while the
+fp32 running max / denominator / accumulator stay in VMEM scratch.  Blocks
+outside a query block's causal (and sliding-window) range skip their
+compute, and their index map is clamped into the range so the pipeline
+issues no DMA for them: work per query block is O(min(q_end, window)).
 
 GQA is expressed in the BlockSpec index_map: the KV block for query head h is
 loaded from kv head h // (Hq // Hkv) — no materialized repeat.
@@ -23,54 +24,73 @@ from jax.experimental.pallas import tpu as pltpu
 NEG_INF = -1e30
 
 
-def _flash_kernel(q_ref, k_ref, v_ref, o_ref, *, block_k: int, scale: float,
-                  window: int, q_offset: int):
-    """Refs: q (1, 1, bq, d); k/v (1, 1, Tk, d); o (1, 1, bq, d)."""
-    block_q, d = q_ref.shape[2], q_ref.shape[3]
-    Tk = k_ref.shape[2]
+def _online_softmax_step(q, k_ref, v_ref, mask, m_ref, l_ref, acc_ref):
+    """One KV block of flash-v2: fold ``softmax(q k^T)`` over the masked
+    (bq, bk) tile into the running (m, l, acc) carry."""
+    k = k_ref[...].astype(jnp.float32)
+    v = v_ref[...].astype(jnp.float32)
+    s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                            preferred_element_type=jnp.float32)
+    s = jnp.where(mask, s, NEG_INF)
+    m_prev = m_ref[...]
+    m_new = jnp.maximum(m_prev, s.max(axis=1, keepdims=True))
+    p = jnp.where(mask, jnp.exp(s - m_new), 0.0)
+    corr = jnp.exp(m_prev - m_new)
+    l_ref[...] = l_ref[...] * corr + p.sum(axis=1, keepdims=True)
+    acc_ref[...] = acc_ref[...] * corr + jax.lax.dot_general(
+        p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+    m_ref[...] = m_new
 
-    qi = pl.program_id(2)
-    q_start = qi * block_q + q_offset          # absolute position of q row 0
-    q = q_ref[0, 0].astype(jnp.float32) * scale
-    q_pos = q_start + jax.lax.iota(jnp.int32, block_q)
 
-    # causal upper bound: last query in the block attends up to q_end
-    q_end = q_start + block_q                  # exclusive
-    hi = jnp.minimum(pl.cdiv(q_end, block_k), Tk // block_k)
-    lo = 0
+def _init(m_ref, l_ref, acc_ref):
+    m_ref[...] = jnp.full_like(m_ref, NEG_INF)
+    l_ref[...] = jnp.zeros_like(l_ref)
+    acc_ref[...] = jnp.zeros_like(acc_ref)
+
+
+def _scratch(block_q, d):
+    return [pltpu.VMEM((block_q, 1), jnp.float32),      # running max
+            pltpu.VMEM((block_q, 1), jnp.float32),      # running denom
+            pltpu.VMEM((block_q, d), jnp.float32)]      # output accumulator
+
+
+def _kv_range(q_start, block_q, block_k, window, lo_tok=0):
+    """[lo, hi) KV blocks a query block starting at ``q_start`` can see."""
+    hi = pl.cdiv(q_start + block_q, block_k)
+    lo = lo_tok // block_k
     if window > 0:
-        lo = jnp.maximum((q_start - window + 1) // block_k, 0)
+        lo = jnp.maximum(lo, (q_start - window + 1) // block_k)
+    return jnp.maximum(lo, 0), hi
 
-    def body(kb, carry):
-        m_prev, l_prev, acc = carry
-        k_blk = jax.lax.dynamic_slice(
-            k_ref[0, 0], (kb * block_k, 0), (block_k, d)).astype(jnp.float32)
-        v_blk = jax.lax.dynamic_slice(
-            v_ref[0, 0], (kb * block_k, 0), (block_k, d)).astype(jnp.float32)
-        k_pos = kb * block_k + jax.lax.iota(jnp.int32, block_k)
 
-        s = jax.lax.dot_general(q, k_blk, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32)
-        mask = q_pos[:, None] >= k_pos[None, :]
+def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
+                  block_k: int, scale: float, window: int, q_offset: int):
+    """Grid (B, Hq, Tq // bq, Tk // bk).  Blocks: q/o (bq, d); k/v (bk, d)."""
+    block_q = q_ref.shape[0]
+    qi, kb = pl.program_id(2), pl.program_id(3)
+    q_start = qi * block_q + q_offset          # absolute position of q row 0
+    lo, hi = _kv_range(q_start, block_q, block_k, window)
+
+    @pl.when(kb == 0)
+    def _():
+        _init(m_ref, l_ref, acc_ref)
+
+    @pl.when((kb >= lo) & (kb < hi))
+    def _():
+        q_pos = q_start + jax.lax.broadcasted_iota(
+            jnp.int32, (block_q, block_k), 0)
+        k_pos = kb * block_k + jax.lax.broadcasted_iota(
+            jnp.int32, (block_q, block_k), 1)
+        mask = q_pos >= k_pos
         if window > 0:
-            mask &= (q_pos[:, None] - k_pos[None, :]) < window
-        s = jnp.where(mask, s, NEG_INF)
+            mask &= (q_pos - k_pos) < window
+        q = q_ref[...].astype(jnp.float32) * scale
+        _online_softmax_step(q, k_ref, v_ref, mask, m_ref, l_ref, acc_ref)
 
-        m_new = jnp.maximum(m_prev, s.max(axis=1))
-        p = jnp.exp(s - m_new[:, None])
-        p = jnp.where(mask, p, 0.0)
-        corr = jnp.exp(m_prev - m_new)
-        l_new = l_prev * corr + p.sum(axis=1)
-        acc = acc * corr[:, None] + jax.lax.dot_general(
-            p, v_blk, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        return m_new, l_new, acc
-
-    m0 = jnp.full((block_q,), NEG_INF, jnp.float32)
-    l0 = jnp.zeros((block_q,), jnp.float32)
-    acc0 = jnp.zeros((block_q, d), jnp.float32)
-    m, l, acc = jax.lax.fori_loop(lo, hi, body, (m0, l0, acc0))
-    o_ref[0, 0] = (acc / jnp.maximum(l, 1e-30)[:, None]).astype(o_ref.dtype)
+    @pl.when(kb == pl.num_programs(3) - 1)
+    def _():
+        o_ref[...] = (acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)
+                      ).astype(o_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("block_q", "block_k", "scale",
@@ -93,36 +113,42 @@ def flash_attention_pallas(q, k, v, *, block_q: int = 128, block_k: int = 128,
     scale = scale if scale is not None else d ** -0.5
     if q_offset is None:
         q_offset = Tk - Tq
+    n_kb = Tk // block_k
 
-    grid = (B, Hq, Tq // block_q)
+    def kv_index(b, h, i, j):
+        lo, hi = _kv_range(i * block_q + q_offset, block_q, block_k, window)
+        j = jnp.clip(j, lo, jnp.minimum(hi, n_kb) - 1)  # no DMA off-range
+        return (b, h // n_rep, j, 0)
+
     kernel = functools.partial(_flash_kernel, block_k=block_k, scale=scale,
                                window=window, q_offset=q_offset)
-    out = pl.pallas_call(
+    q_spec = pl.BlockSpec((None, None, block_q, d),
+                          lambda b, h, i, j: (b, h, i, 0))
+    kv_spec = pl.BlockSpec((None, None, block_k, d), kv_index)
+    return pl.pallas_call(
         kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, 1, block_q, d), lambda b, h, i: (b, h, i, 0)),
-            pl.BlockSpec((1, 1, Tk, d), lambda b, h, i: (b, h // n_rep, 0, 0)),
-            pl.BlockSpec((1, 1, Tk, d), lambda b, h, i: (b, h // n_rep, 0, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, 1, block_q, d), lambda b, h, i: (b, h, i, 0)),
+        grid=(B, Hq, Tq // block_q, n_kb),
+        in_specs=[q_spec, kv_spec, kv_spec],
+        out_specs=q_spec,
         out_shape=jax.ShapeDtypeStruct((B, Hq, Tq, d), q.dtype),
+        scratch_shapes=_scratch(block_q, d),
+        compiler_params=pltpu.CompilerParams(dimension_semantics=(
+            "parallel", "parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(q, k, v)
-    return out
 
 
 # --------------------------------------------------------------- packed varlen
-def _flash_varlen_kernel(seg_smem_ref, cu_ref, seg_ref, q_ref, k_ref, v_ref,
-                         o_ref, *, block_k: int, scale: float, window: int):
+def _flash_varlen_kernel(seg_smem_ref, cu_ref, segq_ref, segk_ref, q_ref,
+                         k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
+                         block_k: int, scale: float, window: int):
     """Packed ragged self-attention over a flattened token stream.
 
-    Grid: (Hq, Tp // block_q).  Scalar-prefetch (SMEM):
+    Grid: (Hq, Tp // block_q, Tp // block_k).  Scalar-prefetch (SMEM):
       seg_smem_ref: (Tp,)  — segment id per packed token (-1 = padding)
       cu_ref:       (N+1,) — cu_seqlens, segment s spans [cu[s], cu[s+1])
-    VMEM refs:
-      seg_ref: (1, Tp)          — same segment ids, vector-readable
-      q_ref:   (1, block_q, d); k_ref, v_ref: (1, Tp, d); o_ref like q_ref.
+    VMEM blocks: segq (bq, 1) / segk (1, bk) — the same segment ids as a
+    column and a row; q / o (bq, d); k / v (bk, d).
 
     The causal mask uses GLOBAL packed positions — within a segment global
     order equals local order, and the (seg_q == seg_k) term removes every
@@ -131,56 +157,39 @@ def _flash_varlen_kernel(seg_smem_ref, cu_ref, seg_ref, q_ref, k_ref, v_ref,
     [segment start of the block's first query, query block end), so work per
     query block is O(its own segment), not O(total).
     """
-    block_q, d = q_ref.shape[1], q_ref.shape[2]
-    Tp = k_ref.shape[1]
-
-    qi = pl.program_id(1)
+    block_q = q_ref.shape[0]
+    qi, kb = pl.program_id(1), pl.program_id(2)
     q_start = qi * block_q
-    q = q_ref[0].astype(jnp.float32) * scale
-    q_pos = q_start + jax.lax.iota(jnp.int32, block_q)
-    seg_q = jax.lax.dynamic_slice(seg_ref[0], (q_start,), (block_q,))
+    lo, hi = _kv_range(q_start, block_q, block_k, window,
+                       _segment_start(seg_smem_ref, cu_ref, q_start))
 
-    # first query's segment start bounds every key this block can see
-    # (padding rows have seg = -1: clamp to 0 so the SMEM read stays in range)
-    first_seg = jnp.maximum(seg_smem_ref[q_start], 0)
-    seg_lo = cu_ref[first_seg]
-    lo = seg_lo // block_k
-    if window > 0:
-        lo = jnp.maximum(lo, (q_start - window + 1) // block_k)
-    hi = jnp.minimum(pl.cdiv(q_start + block_q, block_k), Tp // block_k)
+    @pl.when(kb == 0)
+    def _():
+        _init(m_ref, l_ref, acc_ref)
 
-    def body(kb, carry):
-        m_prev, l_prev, acc = carry
-        k_blk = jax.lax.dynamic_slice(
-            k_ref[0], (kb * block_k, 0), (block_k, d)).astype(jnp.float32)
-        v_blk = jax.lax.dynamic_slice(
-            v_ref[0], (kb * block_k, 0), (block_k, d)).astype(jnp.float32)
-        k_pos = kb * block_k + jax.lax.iota(jnp.int32, block_k)
-        seg_k = jax.lax.dynamic_slice(seg_ref[0], (kb * block_k,), (block_k,))
-
-        s = jax.lax.dot_general(q, k_blk, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32)
-        mask = (seg_q[:, None] == seg_k[None, :]) & \
-            (q_pos[:, None] >= k_pos[None, :])
+    @pl.when((kb >= lo) & (kb < hi))
+    def _():
+        q_pos = q_start + jax.lax.broadcasted_iota(
+            jnp.int32, (block_q, block_k), 0)
+        k_pos = kb * block_k + jax.lax.broadcasted_iota(
+            jnp.int32, (block_q, block_k), 1)
+        mask = (segq_ref[...] == segk_ref[...]) & (q_pos >= k_pos)
         if window > 0:
-            mask &= (q_pos[:, None] - k_pos[None, :]) < window
-        s = jnp.where(mask, s, NEG_INF)
+            mask &= (q_pos - k_pos) < window
+        q = q_ref[...].astype(jnp.float32) * scale
+        _online_softmax_step(q, k_ref, v_ref, mask, m_ref, l_ref, acc_ref)
 
-        m_new = jnp.maximum(m_prev, s.max(axis=1))
-        p = jnp.exp(s - m_new[:, None])
-        p = jnp.where(mask, p, 0.0)
-        corr = jnp.exp(m_prev - m_new)
-        l_new = l_prev * corr + p.sum(axis=1)
-        acc = acc * corr[:, None] + jax.lax.dot_general(
-            p, v_blk, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        return m_new, l_new, acc
+    @pl.when(kb == pl.num_programs(2) - 1)
+    def _():
+        o_ref[...] = (acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)
+                      ).astype(o_ref.dtype)
 
-    m0 = jnp.full((block_q,), NEG_INF, jnp.float32)
-    l0 = jnp.zeros((block_q,), jnp.float32)
-    acc0 = jnp.zeros((block_q, d), jnp.float32)
-    m, l, acc = jax.lax.fori_loop(lo, hi, body, (m0, l0, acc0))
-    o_ref[0] = (acc / jnp.maximum(l, 1e-30)[:, None]).astype(o_ref.dtype)
+
+def _segment_start(seg_smem_ref, cu_ref, q_start):
+    """First packed position of the segment holding token ``q_start`` — it
+    bounds every key its query block can see (padding rows have seg = -1:
+    clamp to 0 so the SMEM read stays in range)."""
+    return cu_ref[jnp.maximum(seg_smem_ref[q_start], 0)]
 
 
 @functools.partial(jax.jit, static_argnames=("block_q", "block_k", "scale",
@@ -203,26 +212,39 @@ def flash_attention_varlen_pallas(q, k, v, seg, cu_seqlens, *,
     assert Hq % Hkv == 0
     n_rep = Hq // Hkv
     scale = scale if scale is not None else d ** -0.5
-    seg2d = seg.reshape(1, Tp)
+    n_kb = Tp // block_k
+
+    def kv_block(i, j, sg, cu):
+        q_start = i * block_q
+        lo, hi = _kv_range(q_start, block_q, block_k, window,
+                           _segment_start(sg, cu, q_start))
+        return jnp.clip(j, lo, jnp.minimum(hi, n_kb) - 1)
 
     kernel = functools.partial(_flash_varlen_kernel, block_k=block_k,
                                scale=scale, window=window)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(Hq, Tp // block_q),
+        grid=(Hq, Tp // block_q, n_kb),
         in_specs=[
-            pl.BlockSpec((1, Tp), lambda h, i, sg, cu: (0, 0)),       # seg
-            pl.BlockSpec((1, block_q, d), lambda h, i, sg, cu: (h, i, 0)),
-            pl.BlockSpec((1, Tp, d), lambda h, i, sg, cu: (h // n_rep, 0, 0)),
-            pl.BlockSpec((1, Tp, d), lambda h, i, sg, cu: (h // n_rep, 0, 0)),
+            pl.BlockSpec((block_q, 1), lambda h, i, j, sg, cu: (i, 0)),
+            pl.BlockSpec((1, block_k),
+                         lambda h, i, j, sg, cu: (0, kv_block(i, j, sg, cu))),
+            pl.BlockSpec((None, block_q, d), lambda h, i, j, sg, cu: (h, i, 0)),
+            pl.BlockSpec((None, block_k, d), lambda h, i, j, sg, cu:
+                         (h // n_rep, kv_block(i, j, sg, cu), 0)),
+            pl.BlockSpec((None, block_k, d), lambda h, i, j, sg, cu:
+                         (h // n_rep, kv_block(i, j, sg, cu), 0)),
         ],
-        out_specs=pl.BlockSpec((1, block_q, d),
-                               lambda h, i, sg, cu: (h, i, 0)),
+        out_specs=pl.BlockSpec((None, block_q, d),
+                               lambda h, i, j, sg, cu: (h, i, 0)),
+        scratch_shapes=_scratch(block_q, d),
     )
-    out = pl.pallas_call(
+    return pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((Hq, Tp, d), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
-    )(seg, cu_seqlens.astype(jnp.int32), seg2d, q, k, v)
-    return out
+    )(seg, cu_seqlens.astype(jnp.int32), seg.reshape(Tp, 1),
+      seg.reshape(1, Tp), q, k, v)

@@ -19,12 +19,13 @@ o_pre_i = sum_j P_ij v_j; out_i = r_i * o_pre_i; g = d out):
 
 ``delta`` and ``dr`` are O(S*d) elementwise reductions computed in plain jnp
 by the wrapper (``mosa_vjp.py``); the two kernels here carry the O(S^2*d)
-work and parallelize the same way the forward does — one (batch*head) slice
-per grid step, the dq kernel blocked over QUERIES, the dk/dv kernel blocked
-over KEYS, each streaming the opposite operand through VMEM:
+work with the forward's tiling (ids as columns/rows, see
+``mosa_attention.py``) — one (batch*head) slice per outer grid index, the
+opposite operand streamed along the innermost ``arbitrary`` axis with an
+fp32 accumulator in VMEM scratch:
 
-  _mosa_bwd_dq_kernel   grid (BH, S // block_q) -> dq block
-  _mosa_bwd_dkv_kernel  grid (BH, S // block_k) -> dk, dv blocks
+  _dq_kernel   grid (BH, S // block_q, S // block_k) -> dq block
+  _dkv_kernel  grid (BH, S // block_k, S // block_q) -> dk, dv blocks
 
 Masking note: rows ops.py padded (idx = +INT_MAX) see a garbage-but-finite
 ``lse``; their cotangent ``g~`` arrives as exact zeros (the output slice
@@ -40,121 +41,72 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.mosa_attention import _pair_mask
-
-NEG_INF = -1e30
+from repro.kernels.mosa_attention import SEMANTICS, cols, pair_mask, rows
 
 
-def _mosa_bwd_dq_kernel(idx_ref, seg_ref, q_ref, k_ref, v_ref, gt_ref,
-                        lse_ref, delta_ref, dq_ref, *, block_k: int,
-                        scale: float):
-    """Grid (BH, S // block_q).  Refs (VMEM blocks):
-
-    idx_ref:   (1, S)
-    seg_ref:   (1, S)
-    q_ref:     (1, block_q, d)
-    k_ref:     (1, S, d)
-    v_ref:     (1, S, d)
-    gt_ref:    (1, block_q, d) — g~ = r * g, fp32
-    lse_ref:   (1, block_q)    fp32
-    delta_ref: (1, block_q)    fp32
-    dq_ref:    (1, block_q, d)
-    """
-    block_q, d = q_ref.shape[1], q_ref.shape[2]
-    S = k_ref.shape[1]
-    n_kb = S // block_k
-
+def _probs(q_ref, k_ref, idq_ref, sgq_ref, idk_ref, sgk_ref, lse_ref, scale):
+    """Recomputed masked probabilities P (bq, bk) and the fp32 q / k tiles."""
     q = q_ref[0].astype(jnp.float32)                           # (bq, d)
-    gt = gt_ref[0].astype(jnp.float32)
-    lse = lse_ref[0]
-    delta = delta_ref[0]
-    qi = pl.program_id(1)
-    idx_q = jax.lax.dynamic_slice(idx_ref[0], (qi * block_q,), (block_q,))
-    seg_q = jax.lax.dynamic_slice(seg_ref[0], (qi * block_q,), (block_q,))
-
-    def body(kb, acc):
-        k_blk = jax.lax.dynamic_slice(
-            k_ref[0], (kb * block_k, 0), (block_k, d)).astype(jnp.float32)
-        v_blk = jax.lax.dynamic_slice(
-            v_ref[0], (kb * block_k, 0), (block_k, d)).astype(jnp.float32)
-        idx_k = jax.lax.dynamic_slice(idx_ref[0], (kb * block_k,), (block_k,))
-        seg_k = jax.lax.dynamic_slice(seg_ref[0], (kb * block_k,), (block_k,))
-
-        s = jax.lax.dot_general(q, k_blk, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32) * scale
-        mask = _pair_mask(idx_q, idx_k, seg_q, seg_k)
-        p = jnp.where(mask, jnp.exp(s - lse[:, None]), 0.0)    # (bq, bk)
-        dp = jax.lax.dot_general(gt, v_blk, (((1,), (1,)), ((), ())),
-                                 preferred_element_type=jnp.float32)
-        ds = p * (dp - delta[:, None])
-        return acc + jax.lax.dot_general(
-            ds, k_blk, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-
-    acc0 = jnp.zeros((block_q, d), jnp.float32)
-    dq = jax.lax.fori_loop(0, n_kb, body, acc0) * scale
-    dq_ref[0] = dq.astype(dq_ref.dtype)
-
-
-def _mosa_bwd_dkv_kernel(idx_ref, seg_ref, q_ref, k_ref, v_ref, gt_ref,
-                         lse_ref, delta_ref, dk_ref, dv_ref, *, block_q: int,
-                         scale: float):
-    """Grid (BH, S // block_k).  Refs:
-
-    idx_ref:   (1, S)
-    seg_ref:   (1, S)
-    q_ref:     (1, S, d) — all queries
-    k_ref:     (1, block_k, d)
-    v_ref:     (1, block_k, d)
-    gt_ref:    (1, S, d) fp32
-    lse_ref:   (1, S)    fp32
-    delta_ref: (1, S)    fp32
-    dk_ref:    (1, block_k, d)
-    dv_ref:    (1, block_k, d)
-    """
-    block_k, d = k_ref.shape[1], k_ref.shape[2]
-    S = q_ref.shape[1]
-    n_qb = S // block_q
-
     k = k_ref[0].astype(jnp.float32)                           # (bk, d)
+    s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                            preferred_element_type=jnp.float32) * scale
+    mask = pair_mask(idq_ref[0], sgq_ref[0], idk_ref[0], sgk_ref[0])
+    return jnp.where(mask, jnp.exp(s - lse_ref[0]), 0.0), q, k
+
+
+def _dq_kernel(idq_ref, sgq_ref, idk_ref, sgk_ref, q_ref, k_ref, v_ref,
+               gt_ref, lse_ref, delta_ref, dq_ref, acc_ref, *, scale: float):
+    """Grid (BH, S // block_q, S // block_k); key blocks stream along j.
+    gt (= r * g) is (1, bq, d) fp32; lse, delta are (1, bq, 1) columns."""
+    kb = pl.program_id(2)
+
+    @pl.when(kb == 0)
+    def _init():
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    p, _, k = _probs(q_ref, k_ref, idq_ref, sgq_ref, idk_ref, sgk_ref,
+                     lse_ref, scale)
     v = v_ref[0].astype(jnp.float32)
-    ki = pl.program_id(1)
-    idx_k = jax.lax.dynamic_slice(idx_ref[0], (ki * block_k,), (block_k,))
-    seg_k = jax.lax.dynamic_slice(seg_ref[0], (ki * block_k,), (block_k,))
+    dp = jax.lax.dot_general(gt_ref[0], v, (((1,), (1,)), ((), ())),
+                             preferred_element_type=jnp.float32)
+    ds = p * (dp - delta_ref[0])
+    acc_ref[...] += jax.lax.dot_general(
+        ds, k, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
 
-    def body(qb, carry):
-        dk_acc, dv_acc = carry
-        q_blk = jax.lax.dynamic_slice(
-            q_ref[0], (qb * block_q, 0), (block_q, d)).astype(jnp.float32)
-        gt_blk = jax.lax.dynamic_slice(
-            gt_ref[0], (qb * block_q, 0), (block_q, d)).astype(jnp.float32)
-        lse_blk = jax.lax.dynamic_slice(lse_ref[0], (qb * block_q,),
-                                        (block_q,))
-        delta_blk = jax.lax.dynamic_slice(delta_ref[0], (qb * block_q,),
-                                          (block_q,))
-        idx_q = jax.lax.dynamic_slice(idx_ref[0], (qb * block_q,), (block_q,))
-        seg_q = jax.lax.dynamic_slice(seg_ref[0], (qb * block_q,), (block_q,))
+    @pl.when(kb == pl.num_programs(2) - 1)
+    def _finish():
+        dq_ref[0] = (acc_ref[...] * scale).astype(dq_ref.dtype)
 
-        s = jax.lax.dot_general(q_blk, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32) * scale
-        mask = _pair_mask(idx_q, idx_k, seg_q, seg_k)
-        p = jnp.where(mask, jnp.exp(s - lse_blk[:, None]), 0.0)  # (bq, bk)
-        dv_acc = dv_acc + jax.lax.dot_general(
-            p, gt_blk, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        dp = jax.lax.dot_general(gt_blk, v, (((1,), (1,)), ((), ())),
-                                 preferred_element_type=jnp.float32)
-        ds = p * (dp - delta_blk[:, None])
-        dk_acc = dk_acc + jax.lax.dot_general(
-            ds, q_blk, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        return dk_acc, dv_acc
 
-    z = jnp.zeros((block_k, d), jnp.float32)
-    dk, dv = jax.lax.fori_loop(0, n_qb, body, (z, z))
-    dk_ref[0] = (dk * scale).astype(dk_ref.dtype)
-    dv_ref[0] = dv.astype(dv_ref.dtype)
+def _dkv_kernel(idq_ref, sgq_ref, idk_ref, sgk_ref, q_ref, k_ref, v_ref,
+                gt_ref, lse_ref, delta_ref, dk_ref, dv_ref, dk_acc, dv_acc,
+                *, scale: float):
+    """Grid (BH, S // block_k, S // block_q); query blocks stream along i."""
+    qb = pl.program_id(2)
+
+    @pl.when(qb == 0)
+    def _init():
+        dk_acc[...] = jnp.zeros_like(dk_acc)
+        dv_acc[...] = jnp.zeros_like(dv_acc)
+
+    p, q, _ = _probs(q_ref, k_ref, idq_ref, sgq_ref, idk_ref, sgk_ref,
+                     lse_ref, scale)
+    gt = gt_ref[0]
+    v = v_ref[0].astype(jnp.float32)
+    dv_acc[...] += jax.lax.dot_general(
+        p, gt, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+    dp = jax.lax.dot_general(gt, v, (((1,), (1,)), ((), ())),
+                             preferred_element_type=jnp.float32)
+    ds = p * (dp - delta_ref[0])
+    dk_acc[...] += jax.lax.dot_general(
+        ds, q, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+
+    @pl.when(qb == pl.num_programs(2) - 1)
+    def _finish():
+        dk_ref[0] = (dk_acc[...] * scale).astype(dk_ref.dtype)
+        dv_ref[0] = dv_acc[...].astype(dv_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("block_q", "block_k", "scale",
@@ -175,57 +127,46 @@ def mosa_attention_bwd_pallas(q, k, v, idx, seg, gt, lse, delta, *,
     BH = B * H
     qf, kf, vf = (x.reshape(BH, S, d) for x in (q, k, v))
     gtf = gt.reshape(BH, S, d).astype(jnp.float32)
-    idxf = idx.reshape(BH, S)
-    segf = seg.reshape(BH, S)
-    lsef = lse.reshape(BH, S)
-    deltaf = delta.reshape(BH, S)
+    idxf, segf = idx.reshape(BH, S), seg.reshape(BH, S)
+    args = (cols(idxf), cols(segf), rows(idxf), rows(segf), qf, kf, vf, gtf,
+            cols(lse.reshape(BH, S)), cols(delta.reshape(BH, S)))
 
-    row = lambda b, i: (b, 0)
-    blk1 = lambda b, i: (b, i)
-    rowd = lambda b, i: (b, 0, 0)
-    blkd = lambda b, i: (b, i, 0)
+    def specs(qi, ki):
+        """BlockSpecs for ``args``; ``qi``/``ki`` pick the query / key block
+        index out of the grid indices (i, j)."""
+        col = pl.BlockSpec((1, block_q, 1), lambda b, i, j: (b, qi(i, j), 0))
+        row = pl.BlockSpec((1, 1, block_k), lambda b, i, j: (b, 0, ki(i, j)))
+        q_blk = pl.BlockSpec((1, block_q, d),
+                             lambda b, i, j: (b, qi(i, j), 0))
+        k_blk = pl.BlockSpec((1, block_k, d),
+                             lambda b, i, j: (b, ki(i, j), 0))
+        return [col, col, row, row, q_blk, k_blk, k_blk, q_blk, col, col]
 
+    q_major = specs(lambda i, j: i, lambda i, j: j)
     dq = pl.pallas_call(
-        functools.partial(_mosa_bwd_dq_kernel, block_k=block_k, scale=scale),
-        grid=(BH, S // block_q),
-        in_specs=[
-            pl.BlockSpec((1, S), row),                 # idx
-            pl.BlockSpec((1, S), row),                 # seg
-            pl.BlockSpec((1, block_q, d), blkd),       # q
-            pl.BlockSpec((1, S, d), rowd),             # k
-            pl.BlockSpec((1, S, d), rowd),             # v
-            pl.BlockSpec((1, block_q, d), blkd),       # gt
-            pl.BlockSpec((1, block_q), blk1),          # lse
-            pl.BlockSpec((1, block_q), blk1),          # delta
-        ],
-        out_specs=pl.BlockSpec((1, block_q, d), blkd),
+        functools.partial(_dq_kernel, scale=scale),
+        grid=(BH, S // block_q, S // block_k),
+        in_specs=q_major,
+        out_specs=q_major[4],
         out_shape=jax.ShapeDtypeStruct((BH, S, d), q.dtype),
+        scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
+        compiler_params=SEMANTICS,
         interpret=interpret,
-    )(idxf, segf, qf, kf, vf, gtf, lsef, deltaf)
+    )(*args)
 
+    k_major = specs(lambda i, j: j, lambda i, j: i)
     dk, dv = pl.pallas_call(
-        functools.partial(_mosa_bwd_dkv_kernel, block_q=block_q, scale=scale),
-        grid=(BH, S // block_k),
-        in_specs=[
-            pl.BlockSpec((1, S), row),                 # idx
-            pl.BlockSpec((1, S), row),                 # seg
-            pl.BlockSpec((1, S, d), rowd),             # q
-            pl.BlockSpec((1, block_k, d), blkd),       # k
-            pl.BlockSpec((1, block_k, d), blkd),       # v
-            pl.BlockSpec((1, S, d), rowd),             # gt
-            pl.BlockSpec((1, S), row),                 # lse
-            pl.BlockSpec((1, S), row),                 # delta
-        ],
-        out_specs=[
-            pl.BlockSpec((1, block_k, d), blkd),
-            pl.BlockSpec((1, block_k, d), blkd),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((BH, S, d), k.dtype),
-            jax.ShapeDtypeStruct((BH, S, d), v.dtype),
-        ],
+        functools.partial(_dkv_kernel, scale=scale),
+        grid=(BH, S // block_k, S // block_q),
+        in_specs=k_major,
+        out_specs=[k_major[5], k_major[6]],
+        out_shape=[jax.ShapeDtypeStruct((BH, S, d), k.dtype),
+                   jax.ShapeDtypeStruct((BH, S, d), v.dtype)],
+        scratch_shapes=[pltpu.VMEM((block_k, d), jnp.float32),
+                        pltpu.VMEM((block_k, d), jnp.float32)],
+        compiler_params=SEMANTICS,
         interpret=interpret,
-    )(idxf, segf, qf, kf, vf, gtf, lsef, deltaf)
+    )(*args)
 
     return (dq.reshape(B, H, S, d), dk.reshape(B, H, S, d),
             dv.reshape(B, H, S, d))

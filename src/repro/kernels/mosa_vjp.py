@@ -49,7 +49,7 @@ def _build(block_q: int, block_k: int, scale: float, interpret: bool):
                                      interpret=interpret)
 
     def fwd(q, k, v, idx, seg, r):
-        o_pre, lse = mosa_attention_fwd_res(q, k, v, idx, seg, r,
+        o_pre, lse = mosa_attention_fwd_res(q, k, v, idx, seg,
                                             block_q=block_q, block_k=block_k,
                                             scale=scale, interpret=interpret)
         rf = r.astype(jnp.float32)

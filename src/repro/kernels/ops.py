@@ -1,31 +1,24 @@
 """Jit'd public wrappers around the Pallas kernels.
 
 Handles shape hygiene (padding S and d_head to MXU-aligned tiles, unpadding
-outputs) and platform dispatch: on TPU the kernels lower natively; elsewhere
-they run through the Pallas interpreter (set ``REPRO_PALLAS_INTERPRET=0`` to
-force native lowering, e.g. inside TPU tests).
+outputs) and platform dispatch: ``interpret=None`` lowers natively on a TPU
+backend and runs the Pallas interpreter elsewhere
+(``repro.kernels.interpret_default``); pass ``interpret=False`` to compile
+for a TPU explicitly.
 """
 
 from __future__ import annotations
 
 import functools
-import os
 
-import jax
 import jax.numpy as jnp
 
+from repro.kernels import interpret_default
 from repro.kernels.flash_attention import (flash_attention_pallas,
                                            flash_attention_varlen_pallas)
 from repro.kernels.mosa_vjp import mosa_attention_trainable
 
 LANE = 128
-
-
-def _interpret_default() -> bool:
-    env = os.environ.get("REPRO_PALLAS_INTERPRET")
-    if env is not None:
-        return env not in ("0", "false", "False")
-    return jax.default_backend() != "tpu"
 
 
 def _pad_to(x, axis, mult, value=0.0):
@@ -53,8 +46,22 @@ def mosa_attention(q, k, v, idx, r, *, seg=None, block_q: int = 128,
     kernel; under ``jax.grad`` the Pallas backward kernels produce
     dq/dk/dv/dr (pad/slice shape hygiene here differentiates transparently:
     cotangents of the output slice arrive zero-padded).
+
+    Under an ambient mesh (``repro.dist.hints``) the kernel runs once per
+    (batch, head) shard: the SPMD partitioner cannot split a Mosaic kernel,
+    and every (b, h) slice is independent.
     """
-    interpret = _interpret_default() if interpret is None else interpret
+    from repro.dist import hints
+
+    interpret = interpret_default() if interpret is None else interpret
+    kernel = functools.partial(_mosa_attention, block_q=block_q,
+                               block_k=block_k, interpret=interpret)
+    return hints.per_shard(kernel, q, k, v, idx, r,
+                           *(() if seg is None else (seg,)))
+
+
+def _mosa_attention(q, k, v, idx, r, seg=None, *, block_q, block_k,
+                    interpret):
     B, H, S, d = q.shape
     bq = min(block_q, max(8, 1 << (S - 1).bit_length()))
     bk = min(block_k, bq)
@@ -63,7 +70,6 @@ def mosa_attention(q, k, v, idx, r, *, seg=None, block_q: int = 128,
     qp = _pad_to(_pad_to(q, 3, LANE), 2, bq)
     kp = _pad_to(_pad_to(k, 3, LANE), 2, bk)
     vp = _pad_to(_pad_to(v, 3, LANE), 2, bk)
-    Sp = qp.shape[2]
     # pad idx with INT_MAX (mask kills padded keys), r with 0 (zero output)
     idxp = _pad_to(idx, 2, bq, value=jnp.iinfo(jnp.int32).max)
     rp = _pad_to(r, 2, bq, value=0.0)
@@ -78,45 +84,35 @@ def mosa_attention(q, k, v, idx, r, *, seg=None, block_q: int = 128,
 def mosa_block_attention(q, k, v, bidx, rblk, *, sel_block_size: int,
                          T: int, seg=None, block_q: int = 128,
                          block_k: int = 128, interpret: bool | None = None):
-    """Block-choice MoSA inner attention (see kernels/mosa_block.py).
+    """Block-choice MoSA inner attention (DESIGN §10) on the token kernels.
 
     q,k,v: (B,H,S,d) block-major selected tokens, S = NB * sel_block_size;
     bidx: (B,H,NB) selected block indices sorted ascending (-1 = empty);
     rblk: (B,H,NB) fp32 per-block router scores; ``T`` the true sequence
-    length (ragged tail of the last block is masked in-kernel).  ``seg``:
-    optional per-token (B,H,S) segment ids.  Returns (B,H,S,d) in q.dtype.
+    length (ragged tail of the last block is masked).  ``seg``: optional
+    per-token (B,H,S) segment ids.  Returns (B,H,S,d) in q.dtype.
 
-    Differentiable via the ``jax.custom_vjp`` in ``mosa_block.py`` — the
-    router cotangent comes back PER BLOCK.  At ``sel_block_size=1`` this
-    reproduces ``mosa_attention`` bit-for-bit (the maintained invariant:
-    identical tile sizes, identical mask truth table — token padding's
-    idx=+INT_MAX and block padding's bidx=-1 kill the same lanes).
+    Blocks expand to per-token positions (``bidx * bs + offset``); empty
+    slots and ragged tails get position -1, which the token kernel's
+    valid-key term masks out and which leaves their query rows with no key
+    at all (zero output, zero gradient).  The block score broadcasts over
+    its tokens, so autodiff sums the token kernel's router cotangent back
+    PER BLOCK.  At ``sel_block_size=1`` the expansion is the identity and
+    this is ``mosa_attention`` bit for bit (tests/test_block_choice.py).
     """
-    from repro.kernels.mosa_block import mosa_block_attention_trainable
-
-    interpret = _interpret_default() if interpret is None else interpret
     bs = sel_block_size
     assert bs >= 1 and (bs & (bs - 1)) == 0 and bs <= LANE, (
         f"sel_block_size must be a power of two <= {LANE}, got {bs}")
     B, H, S, d = q.shape
-    assert S % bs == 0, (S, bs)
-    bq = min(block_q, max(8, 1 << (S - 1).bit_length()))
-    bk = min(block_k, bq)
-    # bs is a pow2 <= 128 and bq is a pow2 in [max(8, bs), 128]: bs | bq | bk
-    scale = d ** -0.5  # scale on the TRUE head dim, before padding
-
-    qp = _pad_to(_pad_to(q, 3, LANE), 2, bq)
-    kp = _pad_to(_pad_to(k, 3, LANE), 2, bk)
-    vp = _pad_to(_pad_to(v, 3, LANE), 2, bk)
-    # padded block slots: bidx = -1 (mask kills them), rblk = 0 (zero output)
-    bidxp = _pad_to(bidx, 2, bq // bs, value=-1)
-    rblkp = _pad_to(rblk, 2, bq // bs, value=0.0)
-    segp = None if seg is None else _pad_to(seg, 2, bq, value=-1)
-
-    out = mosa_block_attention_trainable(qp, kp, vp, bidxp, rblkp, seg=segp,
-                                         block_q=bq, block_k=bk, scale=scale,
-                                         bs=bs, T=T, interpret=interpret)
-    return out[:, :, :S, :d]
+    NB = bidx.shape[-1]
+    assert S == NB * bs, (S, NB, bs)
+    pos = bidx[..., None] * bs + jnp.arange(bs, dtype=jnp.int32)
+    ok = (bidx[..., None] >= 0) & (pos < T)
+    idx = jnp.where(ok, pos, -1).reshape(B, H, S).astype(jnp.int32)
+    r = jnp.broadcast_to(rblk[..., None].astype(jnp.float32),
+                         (B, H, NB, bs)).reshape(B, H, S)
+    return mosa_attention(q, k, v, idx, r, seg=seg, block_q=block_q,
+                          block_k=block_k, interpret=interpret)
 
 
 def segments_from_cu_seqlens(cu_seqlens, total: int):
@@ -145,7 +141,7 @@ def flash_attention_varlen(q, k, v, cu_seqlens, *, window: int = 0,
     offsets (cu[0] = 0, cu[N] = total).  Attention is causal within each
     segment and never crosses a boundary.  Returns (total, Hq, d) in q.dtype.
     """
-    interpret = _interpret_default() if interpret is None else interpret
+    interpret = interpret_default() if interpret is None else interpret
     total, Hq, d = q.shape
     bq = min(block_q, max(8, 1 << (total - 1).bit_length()))
     bk = min(block_k, bq)
@@ -168,7 +164,7 @@ def flash_attention_varlen(q, k, v, cu_seqlens, *, window: int = 0,
 def flash_attention(q, k, v, *, window: int = 0, block_q: int = 128,
                     block_k: int = 128, interpret: bool | None = None):
     """Causal/windowed GQA flash attention.  q: (B,Hq,Tq,d), k/v (B,Hkv,Tk,d)."""
-    interpret = _interpret_default() if interpret is None else interpret
+    interpret = interpret_default() if interpret is None else interpret
     B, Hq, Tq, d = q.shape
     Tk = k.shape[2]
     bq = min(block_q, max(8, 1 << (Tq - 1).bit_length()))

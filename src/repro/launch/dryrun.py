@@ -248,8 +248,6 @@ def _trip_counts(model, shape):
 def analyze(lowered, compiled, n_devices: int, trip_counts=(1,),
             cfg=None, shape=None):
     ca = compiled.cost_analysis() or {}
-    if isinstance(ca, (list, tuple)):      # jax < 0.5 returns [dict]
-        ca = ca[0] if ca else {}
     ma = compiled.memory_analysis()
     coll = parse_collective_bytes(compiled.as_text(), trip_counts)
     per_dev_flops = float(ca.get("flops", 0.0))

@@ -3,11 +3,8 @@
 A FUNCTION, not a module-level constant: importing this module never touches
 jax device state (the dry-run must set XLA_FLAGS before first init).
 
-Mesh building goes through ``repro.dist.sharding.make_mesh``, which handles
-the jax-version differences around ``axis_types`` (absent before jax 0.5).
-NOTE: importing that module (and hence this one) enables
-``jax_threefry_partitionable`` — required so sharded param init reproduces
-single-device init bit-for-bit; it changes RNG streams vs stock jax defaults.
+Mesh building goes through ``repro.dist.sharding.make_mesh`` (``Auto``
+axes, which the sharding hints require).
 """
 
 from __future__ import annotations

@@ -45,6 +45,7 @@ from repro.dist import sharding as shd
 from repro.dist import hints
 from repro.dist.fault_tolerance import elastic_plan
 from repro.launch import mesh as mesh_lib
+from repro.launch.compile_cache import use_compile_cache
 from repro.nn.module import init_shapes
 from repro.nn.transformer import TransformerLM, sample_logits
 from repro.serve.paged_kv import (PAGED_CACHE_TYPES, POOL_FIELDS,
@@ -240,8 +241,8 @@ class Server:
         # The fused chunk decoder: n decode steps + on-device sampling in one
         # program; caches donated so XLA updates them in place.
         # static_argnums + positional calls: jit rejects kwargs outright when
-        # in_shardings is given (jax 0.4.x), so (n, top_k, return_logits)
-        # travel positionally.  ``temperature`` stays TRACED so sweeping it
+        # in_shardings is given, so (n, top_k, return_logits) travel
+        # positionally.  ``temperature`` stays TRACED so sweeping it
         # never recompiles the n-step program.  ``tok`` inherits its incoming
         # sharding (None): it is a committed on-device array sampled from the
         # previous chunk's (replicated) logits, and pinning it to the batch
@@ -318,7 +319,7 @@ class Server:
             return self.model.prefill_packed(params, tokens, caches, cu,
                                              rows, past_lens)
 
-        _prefill_packed_jit = jax.jit(
+        self._prefill_packed_jit = jax.jit(
             _prefill_packed,
             in_shardings=(self.param_sh, None, self.cache_sh, None, None,
                           None),
@@ -329,8 +330,8 @@ class Server:
             if reg.enabled:
                 reg.inc("server.prefill_dispatches")
                 reg.set("server.prefill_tokens", tokens.shape[-1])
-            return _prefill_packed_jit(params, tokens, caches, cu, rows,
-                                       past_lens)
+            return self._prefill_packed_jit(params, tokens, caches, cu, rows,
+                                            past_lens)
         self.prefill_packed = prefill_packed
         self.snapshot_row = jax.jit(row_snapshot)
         self.restore_row = jax.jit(row_restore, donate_argnums=(0,),
@@ -643,6 +644,7 @@ def main(argv=None):
     p.add_argument("--tpot-slo", type=float, default=0.0,
                    help="TPOT SLO in seconds (0 = no TPOT obligation)")
     args = p.parse_args(argv)
+    use_compile_cache()
 
     akw = {"variant": args.variant} if args.variant else {}
     cfg = get_config(args.arch, preset=args.preset, **akw)

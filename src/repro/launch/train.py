@@ -20,6 +20,7 @@ from __future__ import annotations
 import argparse
 import json
 
+from repro.launch.compile_cache import use_compile_cache
 from repro.train.loop import TrainConfig, Trainer          # noqa: F401
 from repro.train.step import make_train_step               # noqa: F401
 
@@ -62,6 +63,7 @@ def main(argv=None):
                    help="router health via a standalone forward at log "
                         "time instead of in-step aux outputs")
     args = p.parse_args(argv)
+    use_compile_cache()
 
     if args.isoflop:
         from repro.train.isoflop import isoflop_sweep, run_isoflop

@@ -236,7 +236,7 @@ class MoEFFN:
 
     def _ep_call(self, params, x, mesh, dp_axes, axis: str):
         from functools import partial
-        from jax.experimental.shard_map import shard_map
+        from jax import shard_map
         from jax.sharding import PartitionSpec as P
         B, T, h = x.shape
         # divisibility-safe DP: drop axes until their product divides B
@@ -253,7 +253,7 @@ class MoEFFN:
 
         @partial(shard_map, mesh=mesh,
                  in_specs=(P(), P(axis), P(axis), P(axis), xs_spec),
-                 out_specs=(xs_spec, P(), P()), check_rep=False)
+                 out_specs=(xs_spec, P(), P()), check_vma=False)
         def run(router_w, w_gate, w_up, w_down, xb):
             Bl, Tl, _ = xb.shape
             y, me, ce = self._ep_local(router_w, w_gate, w_up, w_down,

@@ -26,9 +26,9 @@ span per line for ad-hoc grepping.
 ``jax.profiler`` passthrough: setting ``tracer.annotate = True`` wraps
 every ``span()`` scope in ``jax.profiler.TraceAnnotation`` so host-side
 spans land on the device timeline too, and ``start_profiler(logdir)`` /
-``stop_profiler()`` bracket a run with ``jax.profiler.start_trace`` when
-the profiler is importable (silently skipped otherwise — CPU smoke images
-stay dependency-free).
+``stop_profiler()`` bracket a run with ``jax.profiler.start_trace`` /
+``stop_trace``.  Both raise what the profiler raises: a trace window that
+silently recorded nothing would read as an idle device.
 """
 
 from __future__ import annotations
@@ -161,24 +161,16 @@ def tracer() -> Tracer:
     return TRACER
 
 
-def start_profiler(logdir: str, annotate: bool = True) -> bool:
+def start_profiler(logdir: str, annotate: bool = True) -> None:
     """Begin a ``jax.profiler`` device trace into ``logdir`` (TensorBoard
-    format) and turn on span annotation.  Returns False (no-op) when the
-    profiler is unavailable."""
-    try:
-        import jax.profiler
-        jax.profiler.start_trace(logdir)
-    except Exception:
-        return False
+    format) and turn on span annotation."""
+    import jax.profiler
+    jax.profiler.start_trace(logdir)
     TRACER.annotate = annotate
-    return True
 
 
-def stop_profiler() -> bool:
+def stop_profiler() -> None:
+    """End the trace ``start_profiler`` began and turn annotation off."""
+    import jax.profiler
     TRACER.annotate = False
-    try:
-        import jax.profiler
-        jax.profiler.stop_trace()
-    except Exception:
-        return False
-    return True
+    jax.profiler.stop_trace()

@@ -17,38 +17,28 @@ contiguous decode path:
     VMEM scratch across the block-grid dimension.  No gather buffer is ever
     materialized.
 
-``paged_attention_decode`` is the public dispatcher (same platform logic as
-``repro.kernels.ops``: native on TPU, interpreter elsewhere unless
-``REPRO_PALLAS_INTERPRET`` overrides).  The windowed ring cache always takes
-the gather path — its KV is bounded by W, so there is no quadratic gather to
-avoid (DESIGN §7).
+``paged_attention_decode`` is the public dispatcher: the kernel where
+Pallas lowers natively, the gather reference where kernels would only be
+interpreted (``repro.kernels.interpret_default`` — the gather is faster than
+an interpreted kernel and bit-identical to the contiguous decode path).  The
+windowed ring cache always takes the gather path — its KV is bounded by W,
+so there is no quadratic gather to avoid (DESIGN §7).
 """
 
 from __future__ import annotations
 
 import functools
-import os
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-try:  # pltpu is importable without TPU hardware; kernels interpret on CPU
-    from jax.experimental.pallas import tpu as pltpu
-except ImportError:  # pragma: no cover
-    pltpu = None
-
+from repro.kernels import interpret_default
 from repro.serve.paged_kv import PagedDenseKVCache
 
 NEG_INF = -1e30
 LANE = 128
-
-
-def _interpret_default() -> bool:
-    env = os.environ.get("REPRO_PALLAS_INTERPRET")
-    if env is not None:
-        return env not in ("0", "false", "False")
-    return jax.default_backend() != "tpu"
 
 
 # ---------------------------------------------------------------- reference
@@ -194,16 +184,16 @@ def paged_attention_decode(q, cache: PagedDenseKVCache, *, scale: float,
                            interpret: bool | None = None):
     """Decode attention of one token per row over a paged dense cache.
 
-    q: (B, Hq, d).  ``impl``: ``"kernel"`` | ``"ref"`` | None (kernel on
-    TPU, ref elsewhere — the gather ref is faster than an interpreted kernel
-    on CPU and bit-identical to the contiguous decode path).
+    q: (B, Hq, d).  ``impl``: ``"kernel"`` | ``"ref"`` | None (the kernel
+    where Pallas lowers natively, the gather ref elsewhere — see the module
+    docstring).
     """
     if impl is None:
-        impl = "kernel" if jax.default_backend() == "tpu" else "ref"
+        impl = "ref" if interpret_default() else "kernel"
     if impl == "ref":
         return paged_attention_ref(q, cache.k, cache.v, cache.block_table,
                                    cache.length, scale)
-    interpret = _interpret_default() if interpret is None else interpret
+    interpret = interpret_default() if interpret is None else interpret
     d = q.shape[-1]
     out = paged_attention_kernel(
         _pad_lane(q), _pad_lane(cache.k), _pad_lane(cache.v),
@@ -257,22 +247,23 @@ def paged_prefill_attention_ref(q, k_pool, v_pool, block_table, row_of_tok,
     return out.reshape(total, Hq, d).astype(q.dtype)
 
 
-def _paged_prefill_kernel(row_ref, bt_ref, q_ref, pos_ref, k_ref, v_ref,
-                          o_ref, m_ref, l_ref, acc_ref, *, bs: int,
+def _paged_prefill_kernel(row_ref, bt_ref, kvlen_ref, q_ref, pos_ref, k_ref,
+                          v_ref, o_ref, m_ref, l_ref, acc_ref, *, bs: int,
                           scale: float):
-    """Grid (Hq, N, nb) — one segment x one query head per (h, n) slice, the
-    row's paged KV streamed block-by-block along i with the online-softmax
-    carry in VMEM scratch (same discipline as ``_paged_kernel``).
+    """Grid (N, nb) — one segment per n, all query heads at once, the row's
+    paged KV streamed block-by-block along i with the online-softmax carry
+    in VMEM scratch (same discipline as ``_paged_kernel``).
 
-    row_ref / bt_ref ride in scalar-prefetch SMEM: the i-th KV block of
-    segment n is DMA'd from physical block ``bt[row[n], i]`` by the index
-    map before the body runs.  Refs: q (1, C, 1, d); pos (1, C) — the
-    per-query absolute KV position (-1 = padding query); k/v (1, bs, 1, d).
+    row_ref / bt_ref / kvlen_ref ride in scalar-prefetch SMEM: the i-th KV
+    block of segment n is DMA'd from physical block ``bt[row[n], i]`` by the
+    index map before the body runs, and blocks at or past the segment's KV
+    length ``kvlen[n]`` skip their compute.  Blocks: q / o (Hq, C, d);
+    pos (C, 1) — the per-query absolute KV position (-1 = padding query);
+    k / v (bs, Hkv, d).
     """
-    i = pl.program_id(2)
-    nb = pl.num_programs(2)
-    C, d = q_ref.shape[1], q_ref.shape[3]
-    pos = pos_ref[0]                                            # (C,)
+    n, i = pl.program_id(0), pl.program_id(1)
+    Hq, C, d = q_ref.shape
+    Hkv = k_ref.shape[1]
 
     @pl.when(i == 0)
     def _init():
@@ -280,84 +271,84 @@ def _paged_prefill_kernel(row_ref, bt_ref, q_ref, pos_ref, k_ref, v_ref,
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    # the segment's deepest query bounds how many KV blocks matter
-    @pl.when(i * bs <= jnp.max(pos))
+    @pl.when(i * bs < kvlen_ref[n])
     def _block():
-        q = q_ref[0, :, 0].astype(jnp.float32) * scale          # (C, d)
-        k = k_ref[0, :, 0].astype(jnp.float32)                  # (bs, d)
-        v = v_ref[0, :, 0].astype(jnp.float32)
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+        q = q_ref[...].astype(jnp.float32) * scale              # (Hq, C, d)
+        k = k_ref[...].astype(jnp.float32).transpose(1, 0, 2)   # (Hkv,bs,d)
+        v = v_ref[...].astype(jnp.float32).transpose(1, 0, 2)
+        if Hq != Hkv:                                           # GQA
+            k = jnp.repeat(k, Hq // Hkv, axis=0)
+            v = jnp.repeat(v, Hq // Hkv, axis=0)
+        s = jax.lax.dot_general(q, k, (((2,), (2,)), ((0,), (0,))),
                                 preferred_element_type=jnp.float32)
-        k_pos = i * bs + jax.lax.iota(jnp.int32, bs)
-        mask = k_pos[None, :] <= pos[:, None]                   # (C, bs)
-        s = jnp.where(mask, s, NEG_INF)
+        k_pos = i * bs + jax.lax.broadcasted_iota(jnp.int32, (1, bs), 1)
+        mask = (k_pos <= pos_ref[...])[None]                    # (1, C, bs)
+        s = jnp.where(mask, s, NEG_INF)                         # (Hq, C, bs)
 
-        m_prev = m_ref[:, :1].reshape(C)
-        l_prev = l_ref[:, :1].reshape(C)
-        m_new = jnp.maximum(m_prev, s.max(axis=-1))
-        p = jnp.exp(s - m_new[:, None])
-        p = jnp.where(mask, p, 0.0)
+        m_prev = m_ref[...]
+        m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
+        p = jnp.where(mask, jnp.exp(s - m_new), 0.0)
         corr = jnp.exp(m_prev - m_new)
-        l_new = l_prev * corr + p.sum(axis=-1)
-        acc = acc_ref[...] * corr[:, None] + jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())),
+        l_ref[...] = l_ref[...] * corr + p.sum(axis=-1, keepdims=True)
+        acc_ref[...] = acc_ref[...] * corr + jax.lax.dot_general(
+            p, v, (((2,), (1,)), ((0,), (0,))),
             preferred_element_type=jnp.float32)
-        m_ref[...] = jnp.broadcast_to(m_new[:, None], m_ref.shape)
-        l_ref[...] = jnp.broadcast_to(l_new[:, None], l_ref.shape)
-        acc_ref[...] = acc
+        m_ref[...] = m_new
 
-    @pl.when(i == nb - 1)
+    @pl.when(i == pl.num_programs(1) - 1)
     def _finish():
-        l = l_ref[:, :1]                                        # (C, 1)
-        o_ref[0, :, 0] = (acc_ref[...] /
-                          jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
+        o_ref[...] = (acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)
+                      ).astype(o_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("scale", "interpret"))
 def paged_prefill_attention_kernel(q_seg, pos_seg, k_pool, v_pool,
                                    block_table, row_of_seg, *, scale: float,
                                    interpret: bool = False):
-    """Pallas packed prefill.  q_seg: (N, C, Hq, d) — the packed chunk
-    unfolded to one right-padded row per segment (d a multiple of 128);
-    pos_seg: (N, C) int32 absolute KV positions (-1 on padding);
-    row_of_seg: (N,) int32 batch row per segment (clamped if -1)."""
-    N, C, Hq, d = q_seg.shape
+    """Pallas packed prefill.  q_seg: (N, Hq, C, d) — the packed chunk
+    unfolded to one right-padded row per segment, head-major (d a multiple
+    of 128); pos_seg: (N, C) int32 absolute KV positions (-1 on padding);
+    row_of_seg: (N,) int32 batch row per segment (clamped if -1).
+    Returns (N, Hq, C, d)."""
+    N, Hq, C, d = q_seg.shape
     nb = block_table.shape[1]
     bs = k_pool.shape[1]
     Hkv = k_pool.shape[2]
-    R = Hq // Hkv
+    kv_len = pos_seg.max(axis=1) + 1                            # (N,)
+
+    def kv_index(n, i, row, bt, kvl):
+        # blocks past the segment's KV length re-map to its last needed
+        # block: the pipeline skips the repeated DMA, the body its compute
+        i = jnp.minimum(i, jnp.maximum(kvl[n] - 1, 0) // bs)
+        return (jnp.maximum(bt[jnp.maximum(row[n], 0), i], 0), 0, 0, 0)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,           # row_of_seg, block_table
-        grid=(Hq, N, nb),
+        num_scalar_prefetch=3,           # row_of_seg, block_table, kv_len
+        grid=(N, nb),
         in_specs=[
-            pl.BlockSpec((1, C, 1, d),
-                         lambda h, n, i, row, bt: (n, 0, h, 0)),
-            pl.BlockSpec((1, C), lambda h, n, i, row, bt: (n, 0)),
-            pl.BlockSpec((1, bs, 1, d),
-                         lambda h, n, i, row, bt:
-                         (jnp.maximum(bt[jnp.maximum(row[n], 0), i], 0),
-                          0, h // R, 0)),
-            pl.BlockSpec((1, bs, 1, d),
-                         lambda h, n, i, row, bt:
-                         (jnp.maximum(bt[jnp.maximum(row[n], 0), i], 0),
-                          0, h // R, 0)),
+            pl.BlockSpec((None, Hq, C, d), lambda n, i, *_: (n, 0, 0, 0)),
+            pl.BlockSpec((None, C, 1), lambda n, i, *_: (n, 0, 0)),
+            pl.BlockSpec((None, bs, Hkv, d), kv_index),
+            pl.BlockSpec((None, bs, Hkv, d), kv_index),
         ],
-        out_specs=pl.BlockSpec((1, C, 1, d),
-                               lambda h, n, i, row, bt: (n, 0, h, 0)),
+        out_specs=pl.BlockSpec((None, Hq, C, d),
+                               lambda n, i, *_: (n, 0, 0, 0)),
         scratch_shapes=[
-            pltpu.VMEM((C, LANE), jnp.float32),    # running max (replicated)
-            pltpu.VMEM((C, LANE), jnp.float32),    # running denom
-            pltpu.VMEM((C, d), jnp.float32),       # output accumulator
+            pltpu.VMEM((Hq, C, 1), jnp.float32),   # running max
+            pltpu.VMEM((Hq, C, 1), jnp.float32),   # running denom
+            pltpu.VMEM((Hq, C, d), jnp.float32),   # output accumulator
         ],
     )
     kernel = functools.partial(_paged_prefill_kernel, bs=bs, scale=scale)
     return pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((N, C, Hq, d), q_seg.dtype),
+        out_shape=jax.ShapeDtypeStruct((N, Hq, C, d), q_seg.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
-    )(row_of_seg, block_table, q_seg, pos_seg, k_pool, v_pool)
+    )(row_of_seg, block_table, kv_len.astype(jnp.int32), q_seg,
+      pos_seg[..., None], k_pool, v_pool)
 
 
 def paged_prefill_attention(q, cache: PagedDenseKVCache, cu_seqlens,
@@ -374,7 +365,7 @@ def paged_prefill_attention(q, cache: PagedDenseKVCache, cu_seqlens,
     ``impl`` as in ``paged_attention_decode``.
     """
     if impl is None:
-        impl = "kernel" if jax.default_backend() == "tpu" else "ref"
+        impl = "ref" if interpret_default() else "kernel"
     total, Hq, d = q.shape
     cu = jnp.asarray(cu_seqlens, jnp.int32)
     t = jnp.arange(total, dtype=jnp.int32)
@@ -390,7 +381,7 @@ def paged_prefill_attention(q, cache: PagedDenseKVCache, cu_seqlens,
             q, cache.k, cache.v, cache.block_table, row_of_tok, pos_in_kv,
             scale)
 
-    interpret = _interpret_default() if interpret is None else interpret
+    interpret = interpret_default() if interpret is None else interpret
     N = cu.shape[0] - 1
     C = total
     # unfold the packed stream to one right-padded row per segment
@@ -401,8 +392,8 @@ def paged_prefill_attention(q, cache: PagedDenseKVCache, cu_seqlens,
     pos_seg = jnp.where(in_seg & (row_of_seg >= 0)[:, None],
                         past_lens[:, None] + jnp.arange(C)[None, :], -1)
     out_seg = paged_prefill_attention_kernel(
-        _pad_lane(q_seg), pos_seg.astype(jnp.int32), _pad_lane(cache.k),
-        _pad_lane(cache.v), cache.block_table,
+        _pad_lane(q_seg.transpose(0, 2, 1, 3)), pos_seg.astype(jnp.int32),
+        _pad_lane(cache.k), _pad_lane(cache.v), cache.block_table,
         row_of_seg.astype(jnp.int32), scale=scale, interpret=interpret)
-    out = out_seg[segc, local][..., :d]                         # (total,Hq,d)
+    out = out_seg[segc, :, local][..., :d]                      # (total,Hq,d)
     return jnp.where((seg >= 0)[:, None, None], out, 0).astype(q.dtype)

@@ -159,7 +159,7 @@ def test_hints_literal_axis_role_passthrough():
 
 
 def test_hints_constrain_roundtrip_values():
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = shd.make_mesh((1, 1), ("data", "model"))
     x = jnp.arange(12.0).reshape(3, 4)
     with sharding_hints(mesh=mesh):
         y = constrain(x, ("dp", "tp"))

@@ -147,7 +147,7 @@ def test_compressed_psum_cross_pod():
     out = run_devices("""
         import jax, jax.numpy as jnp
         from functools import partial
-        from jax.experimental.shard_map import shard_map
+        from jax import shard_map
         from jax.sharding import PartitionSpec as P
         from repro.launch.mesh import make_mesh
         from repro.optim.grad_compression import compressed_psum
@@ -156,13 +156,13 @@ def test_compressed_psum_cross_pod():
         g = jax.random.normal(jax.random.PRNGKey(0), (2, 256))
 
         @partial(shard_map, mesh=mesh, in_specs=P("pod"), out_specs=P("pod"),
-                 check_rep=False)
+                 check_vma=False)
         def reduce_exact(g):
             out, _ = compressed_psum({"g": g}, "pod", "none")
             return out["g"]
 
         @partial(shard_map, mesh=mesh, in_specs=P("pod"), out_specs=P("pod"),
-                 check_rep=False)
+                 check_vma=False)
         def reduce_topk(g):
             out, res = compressed_psum({"g": g}, "pod", "topk", topk_frac=0.5)
             return out["g"] + jax.lax.psum(res["g"], "pod")  # add back residual
@@ -218,3 +218,41 @@ def test_moe_ep_shard_map_matches_vmap_path():
     assert float(out.split("DIFF")[1].split()[0]) < 1e-4
     assert float(out.split("AUXDIFF")[1].split()[0]) < 1e-5
     assert float(out.split("GNORM")[1].split()[0]) > 0
+
+
+def test_mosa_kernel_per_shard_matches_unsharded():
+    """The fused MoSA kernel under a (data, model) mesh runs per (batch,
+    head) shard (a Mosaic kernel cannot be SPMD-partitioned); outputs and
+    all four gradients equal the unsharded call."""
+    out = run_devices("""
+        import jax, jax.numpy as jnp
+        from repro.dist import hints
+        from repro.kernels import ops
+        from repro.launch.mesh import make_mesh
+
+        ks = jax.random.split(jax.random.PRNGKey(0), 5)
+        B, H, S, d = 4, 6, 16, 8
+        q, k, v = (jax.random.normal(kk, (B, H, S, d)) for kk in ks[:3])
+        idx = jnp.sort(jax.random.randint(ks[3], (B, H, S), 0, 64), -1)
+        r = jax.nn.sigmoid(jax.random.normal(ks[4], (B, H, S)))
+
+        def loss(q, k, v, r):
+            return jnp.sum(ops.mosa_attention(q, k, v, idx, r) ** 2)
+
+        def run():
+            return (jax.jit(lambda *a: ops.mosa_attention(*a))(q, k, v, idx, r),
+                    jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3)))(q, k, v, r))
+
+        want = run()
+        mesh = make_mesh((2, 2), ("data", "model"))
+        with mesh, hints.sharding_hints(mesh=mesh):
+            got = run()
+            txt = jax.jit(lambda *a: ops.mosa_attention(*a)).lower(
+                q, k, v, idx, r).as_text()
+        diffs = jax.tree.map(lambda a, b: float(jnp.abs(a - b).max()),
+                             want, got)
+        print("DIFF", max(jax.tree.leaves(diffs)))
+        print("SHARDMAP", "shard_map" in txt or "sdy.manual_computation" in txt)
+    """, n_devices=4)
+    assert float(out.split("DIFF")[1].split()[0]) < 1e-5
+    assert out.split("SHARDMAP")[1].split()[0] == "True"

@@ -220,6 +220,16 @@ def test_set_enabled_toggles_both():
     assert obs.enabled()
 
 
+def test_profiler_failure_raises():
+    """A profiler bracket that cannot trace raises instead of leaving an
+    empty trace window behind, and span annotation ends with it."""
+    from repro.obs.tracing import TRACER, stop_profiler
+    TRACER.annotate = True
+    with pytest.raises(RuntimeError):
+        stop_profiler()                   # no trace was started
+    assert not TRACER.annotate
+
+
 # ------------------------------------------------- scheduler integration
 def _hybrid_cfg():
     """3-layer dense + window + MoSA stack (the paged-serving acceptance

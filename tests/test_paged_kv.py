@@ -15,7 +15,8 @@ import pytest
 
 from repro.core.kv_cache import DenseKVCache, WindowKVCache
 from repro.serve.paged_attention import (paged_attention_kernel,
-                                         paged_attention_ref)
+                                         paged_attention_ref,
+                                         paged_prefill_attention)
 from repro.serve.paged_kv import (BlockPool, PagedDenseKVCache,
                                   PagedWindowKVCache, copy_blocks)
 from repro.serve.prefix_cache import PrefixCache
@@ -238,6 +239,37 @@ def test_paged_attention_kernel_matches_ref():
     ker = paged_attention_kernel(q, k_pool, v_pool, bt, lengths,
                                  scale=d ** -0.5, interpret=True)
     np.testing.assert_allclose(np.asarray(ref), np.asarray(ker),
+                               atol=1e-5, rtol=1e-5)
+
+
+def test_paged_prefill_kernel_matches_ref():
+    """The packed-prefill Pallas kernel (interpret mode on CPU) == the
+    gather reference: GQA, segments continuing a cached past, a segment
+    spanning several KV blocks, and an inactive (row -1) segment slot."""
+    key = jax.random.PRNGKey(5)
+    B, Hq, Hkv, bs, nb, d = 3, 4, 2, 4, 6, 16
+    N = B * nb
+    k_pool = jax.random.normal(key, (N, bs, Hkv, d), jnp.float32)
+    v_pool = jax.random.normal(jax.random.fold_in(key, 1),
+                               (N, bs, Hkv, d), jnp.float32)
+    bt = jnp.asarray(np.random.default_rng(0).permutation(N).reshape(B, nb),
+                     jnp.int32)
+    cache = PagedDenseKVCache(k_pool, v_pool, bt,
+                              jnp.zeros((B,), jnp.int32))
+    # segments of 7, 3 and 9 tokens after 2, 13 and 0 cached tokens, then
+    # an inactive slot; 19 real tokens and 5 of padding
+    total = 24
+    cu = jnp.asarray([0, 7, 10, 19, 19], jnp.int32)
+    rows = jnp.asarray([2, 0, 1, -1], jnp.int32)
+    past_lens = jnp.asarray([2, 13, 0, 0], jnp.int32)
+    q = jax.random.normal(jax.random.fold_in(key, 2), (total, Hq, d),
+                          jnp.float32)
+    want = paged_prefill_attention(q, cache, cu, rows, past_lens,
+                                   scale=d ** -0.5, impl="ref")
+    got = paged_prefill_attention(q, cache, cu, rows, past_lens,
+                                  scale=d ** -0.5, impl="kernel",
+                                  interpret=True)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                atol=1e-5, rtol=1e-5)
 
 
