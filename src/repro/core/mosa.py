@@ -30,8 +30,9 @@ from repro.configs.base import MoSAConfig
 from repro.core import rope as rope_lib
 from repro.dist import hints
 from repro.core.kv_cache import MoSABlockKVCache, MoSAKVCache
-from repro.core.router import (ExpertChoiceRouter, block_pool_scores,
-                               expand_block_index, select_topk, selection_mask,
+from repro.core.router import (ATTEND, UPKEEP, ExpertChoiceRouter,
+                               block_pool_scores, expand_block_index,
+                               select_topk, selection_mask,
                                streaming_topk_update)
 from repro.nn.layers import _trunc_normal
 from repro.nn.module import logical
@@ -562,11 +563,12 @@ class MoSAAttention:
         sel_scores = scores_cat
         if c.force_first_token:
             sel_scores = jnp.where(idx_cat == 0, 2.0, sel_scores)  # boost
-        _, j = jax.lax.top_k(sel_scores, kc)
-        r_sel = jnp.take_along_axis(scores_cat, j, axis=-1)
-        idx_sel = jnp.take_along_axis(idx_cat, j, axis=-1)
-        k_sel = jnp.take_along_axis(k_cat, j[..., None], axis=2)
-        v_sel = jnp.take_along_axis(v_cat, j[..., None], axis=2)
+        with jax.named_scope(UPKEEP):
+            _, j = jax.lax.top_k(sel_scores, kc)
+            r_sel = jnp.take_along_axis(scores_cat, j, axis=-1)
+            idx_sel = jnp.take_along_axis(idx_cat, j, axis=-1)
+            k_sel = jnp.take_along_axis(k_cat, j[..., None], axis=2)
+            v_sel = jnp.take_along_axis(v_cat, j[..., None], axis=2)
 
         sel_ok = r_sel > 0.0          # -inf empties / -1.0 pads drop out
         # One-shot selection width on traced lengths: top_k ordered the
@@ -588,28 +590,31 @@ class MoSAAttention:
         rank_ok = sel_ok & (jnp.arange(kc) < k_eff[:, None, None])
         r_st = jnp.where(sel_ok, r_sel, -jnp.inf)
         idx_st = jnp.where(sel_ok, idx_sel, -1)
-        order = jnp.argsort(jnp.where(idx_st < 0,
-                                      jnp.iinfo(jnp.int32).max, idx_st), -1)
-        idx_st = jnp.take_along_axis(idx_st, order, -1)
-        r_st = jnp.take_along_axis(r_st, order, -1)
-        rank_ok = jnp.take_along_axis(rank_ok, order, -1)
-        k_sel = jnp.take_along_axis(k_sel, order[..., None], 2)
-        v_sel = jnp.take_along_axis(v_sel, order[..., None], 2)
+        with jax.named_scope(UPKEEP):
+            order = jnp.argsort(jnp.where(
+                idx_st < 0, jnp.iinfo(jnp.int32).max, idx_st), -1)
+            idx_st = jnp.take_along_axis(idx_st, order, -1)
+            r_st = jnp.take_along_axis(r_st, order, -1)
+            rank_ok = jnp.take_along_axis(rank_ok, order, -1)
+            k_sel = jnp.take_along_axis(k_sel, order[..., None], 2)
+            v_sel = jnp.take_along_axis(v_sel, order[..., None], 2)
 
         # Suffix-query outputs over the final selection (index-causal mask,
         # router-score scaling) — __call__ restricted to suffix queries.
         # Queries AND keys are rank-masked to the one-shot width.
         is_suffix = rank_ok & (idx_st >= L0[:, None, None]) & (idx_st >= 0)
         t_j = jnp.clip(idx_st - L0[:, None, None], 0, T - 1)
-        q_sel = jnp.take_along_axis(q_all, t_j[..., None], axis=2)
-        s = jnp.einsum("bnqd,bnkd->bnqk", q_sel, k_sel,
-                       preferred_element_type=jnp.float32) * (d ** -0.5)
-        mask = (selection_mask(idx_st, idx_st)
-                & (idx_st >= 0)[:, :, None, :] & rank_ok[:, :, None, :])
-        s = jnp.where(mask, s, NEG_INF)
-        p = jax.nn.softmax(s.astype(jnp.float32), axis=-1)
-        att = jnp.einsum("bnqk,bnkd->bnqd", p.astype(cd), v_sel,
-                         preferred_element_type=jnp.float32)
+        with jax.named_scope(UPKEEP):
+            q_sel = jnp.take_along_axis(q_all, t_j[..., None], axis=2)
+        with jax.named_scope(ATTEND):
+            s = jnp.einsum("bnqd,bnkd->bnqk", q_sel, k_sel,
+                           preferred_element_type=jnp.float32) * (d ** -0.5)
+            mask = (selection_mask(idx_st, idx_st)
+                    & (idx_st >= 0)[:, :, None, :] & rank_ok[:, :, None, :])
+            s = jnp.where(mask, s, NEG_INF)
+            p = jax.nn.softmax(s.astype(jnp.float32), axis=-1)
+            att = jnp.einsum("bnqk,bnkd->bnqd", p.astype(cd), v_sel,
+                             preferred_element_type=jnp.float32)
         r_q = jnp.where(is_suffix, jnp.maximum(r_st, 0.0), 0.0)
         att = att * r_q[..., None]
         y_heads = _per_head(att, params["wo"], cd)
@@ -1064,31 +1069,33 @@ class MoSAAttention:
         kk = rope_lib.apply_rope(kk[:, :, None], pos_t, self.rope_theta,
                                  self.rotary_frac)[:, :, 0]
 
-        selected, slot, new_scores, new_idx = streaming_topk_update(
-            cache.scores, cache.idx, score,
-            jnp.broadcast_to(t[:, None], (B, H)), is_forced)
+        with jax.named_scope(UPKEEP):
+            selected, slot, new_scores, new_idx = streaming_topk_update(
+                cache.scores, cache.idx, score,
+                jnp.broadcast_to(t[:, None], (B, H)), is_forced)
 
-        onehot = jax.nn.one_hot(slot, cache.k.shape[2], dtype=cd)  # (B,H,k)
-        upd = (onehot * selected[..., None].astype(cd))[..., None]
-        new_k = cache.k * (1 - upd) + upd * kk[:, :, None]
-        new_v = cache.v * (1 - upd) + upd * v[:, :, None]
+            onehot = jax.nn.one_hot(slot, cache.k.shape[2], dtype=cd)
+            upd = (onehot * selected[..., None].astype(cd))[..., None]
+            new_k = cache.k * (1 - upd) + upd * kk[:, :, None]
+            new_v = cache.v * (1 - upd) + upd * v[:, :, None]
 
-        # Restore the sorted-ascending slot order (empty slots sort last).
-        order = jnp.argsort(jnp.where(new_idx < 0,
-                                      jnp.iinfo(jnp.int32).max, new_idx), -1)
-        new_idx = jnp.take_along_axis(new_idx, order, -1)
-        new_scores = jnp.take_along_axis(new_scores, order, -1)
-        new_k = jnp.take_along_axis(new_k, order[..., None], 2)
-        new_v = jnp.take_along_axis(new_v, order[..., None], 2)
+            # Restore the sorted-ascending slot order (empties sort last).
+            order = jnp.argsort(jnp.where(
+                new_idx < 0, jnp.iinfo(jnp.int32).max, new_idx), -1)
+            new_idx = jnp.take_along_axis(new_idx, order, -1)
+            new_scores = jnp.take_along_axis(new_scores, order, -1)
+            new_k = jnp.take_along_axis(new_k, order[..., None], 2)
+            new_v = jnp.take_along_axis(new_v, order[..., None], 2)
 
         # Attention of the (possibly inserted) query over the cached set.
-        valid = new_idx >= 0                                       # (B,H,k)
-        s = jnp.einsum("bnd,bnkd->bnk", q, new_k,
-                       preferred_element_type=jnp.float32) * (d ** -0.5)
-        s = jnp.where(valid, s, NEG_INF)
-        p = jax.nn.softmax(s.astype(jnp.float32), axis=-1)
-        att = jnp.einsum("bnk,bnkd->bnd", p.astype(cd), new_v,
-                         preferred_element_type=jnp.float32)
+        with jax.named_scope(ATTEND):
+            valid = new_idx >= 0                                   # (B,H,k)
+            s = jnp.einsum("bnd,bnkd->bnk", q, new_k,
+                           preferred_element_type=jnp.float32) * (d ** -0.5)
+            s = jnp.where(valid, s, NEG_INF)
+            p = jax.nn.softmax(s.astype(jnp.float32), axis=-1)
+            att = jnp.einsum("bnk,bnkd->bnd", p.astype(cd), new_v,
+                             preferred_element_type=jnp.float32)
         att = att * (score * selected.astype(jnp.float32))[..., None]
         y = jnp.einsum("bnd,ndh->bh", att.astype(cd), params["wo"].astype(cd),
                        preferred_element_type=jnp.float32).astype(cd)

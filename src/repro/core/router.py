@@ -22,6 +22,12 @@ import jax.numpy as jnp
 from repro.nn.module import logical
 from repro.nn.layers import _trunc_normal
 
+# ``jax.named_scope`` names of MoSA's compiled operations: they reach each
+# operation's ``op_name`` metadata, so a device trace can attribute time to
+# routing, to stored-set upkeep (top-k, slot writes, re-sort and its
+# gathers) and to attention over the stored set.
+ROUTE, UPKEEP, ATTEND = "mosa.route", "mosa.upkeep", "mosa.attend"
+
 
 @dataclasses.dataclass(frozen=True)
 class ExpertChoiceRouter:
@@ -39,9 +45,11 @@ class ExpertChoiceRouter:
 
     def scores(self, params, x):
         """x: (B, T, h) -> sigmoid scores (B, H, T) in fp32."""
-        logits = jnp.einsum("bth,nh->bnt", x.astype(jnp.float32), params["w"],
-                            preferred_element_type=jnp.float32)
-        return jax.nn.sigmoid(logits)
+        with jax.named_scope(ROUTE):
+            logits = jnp.einsum("bth,nh->bnt", x.astype(jnp.float32),
+                                params["w"],
+                                preferred_element_type=jnp.float32)
+            return jax.nn.sigmoid(logits)
 
 
 def select_topk(scores, k: int, force_first: bool = True):

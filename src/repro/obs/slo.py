@@ -15,7 +15,11 @@ The unit of account is a per-request **record** dict::
 
     {"rid": int, "tenant": str, "outcome": "finished" | "shed",
      "t_arrival": float, "queue_delay_s": float,
-     "ttft_s": float | None, "tpot_s": float | None, "new_tokens": int}
+     "ttft_s": float | None, "tpot_s": float | None,
+     "prefill_s": float | None, "new_tokens": int}
+
+``prefill_s`` is the service part of TTFT: admission to first token, of
+the life that produced the first token.
 
 Two independent producers emit the same schema, and parity between them is
 tested (``tests/test_slo.py``):
@@ -129,7 +133,8 @@ def records_from_spans(spans) -> List[dict]:
 
     Per ``req<rid>`` track: the earliest "queued" span's start is the
     arrival, the last one's duration the (final) queue delay; TTFT is the
-    end of the last non-preempted "prefill" minus arrival; TPOT is the
+    end of the last non-preempted "prefill" minus arrival, ``prefill_s``
+    that prefill's duration (admission to first token); TPOT is the
     "decode" span's duration over its ``tokens - 1`` inter-token gaps;
     outcome comes from the "finish"/"shed" instant (requests that left no
     terminal instant — still queued or in flight when the trace was cut —
@@ -164,11 +169,12 @@ def records_from_spans(spans) -> List[dict]:
                "t_arrival": (min(s.t0 for s in queued) if queued
                              else shed.t0 if shed else 0.0),
                "queue_delay_s": queued[-1].dur if queued else 0.0,
-               "ttft_s": None, "tpot_s": None,
+               "ttft_s": None, "tpot_s": None, "prefill_s": None,
                "new_tokens": finish.args.get("tokens", 0) if finish else 0}
         if finish is not None and prefills:
             p = prefills[-1]
             rec["ttft_s"] = (p.t0 + p.dur) - rec["t_arrival"]
+            rec["prefill_s"] = p.dur
         if finish is not None and decodes:
             d = decodes[-1]
             toks = d.args.get("tokens", 0)

@@ -9,8 +9,9 @@ its own row.
 
 Two recording styles, because the Scheduler interleaves requests:
 
-  * ``with tracer.span("prefill_chunk", track="sched", segs=3):`` — for
-    code where the interval IS a lexical scope;
+  * ``with tracer.span("prefill_chunk", track="sched", segs=3) as args:``
+    — for code where the interval IS a lexical scope; the body may add
+    args it learns only as it runs (``args["leaves"] = n``);
   * ``tracer.add("prefill", t0, t1, track="req3", ...)`` — explicit
     timestamps for phases that open in one scheduler iteration and close
     many iterations later (a request's prefill spans multiple chunks
@@ -76,15 +77,17 @@ class Tracer:
 
     @contextmanager
     def span(self, name: str, track: str = "main", **args):
+        """Record the scope as a span; yields its ``args`` dict, which the
+        body may extend until the scope closes."""
         if not self.enabled:
-            yield
+            yield args
             return
         ann = self._annotation(name)
         if ann is not None:
             ann.__enter__()
         t0 = self.now()
         try:
-            yield
+            yield args
         finally:
             self._push(Span(name, t0, self.now() - t0, track, args))
             if ann is not None:

@@ -112,7 +112,6 @@ class BlockPool:
         ids = [self._free.pop() for _ in range(n)]
         for b in ids:
             self._ref[b] = 1
-        _obs_registry().inc(f"pool.{self.name}.allocs", n)
         self._publish()
         return ids
 

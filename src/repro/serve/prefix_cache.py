@@ -63,6 +63,7 @@ class PrefixCache:
         self.hits = 0
         self.misses = 0
         self.hit_tokens = 0
+        self._n_nodes = 0             # kept by insert / evict_lru
 
     # ------------------------------------------------------------- queries
     def _chain(self, node: _Node) -> List[_Node]:
@@ -100,9 +101,7 @@ class PrefixCache:
             return None, 0
         self.hits += 1
         self.hit_tokens += best.depth
-        reg = _obs_registry()            # mirrors of the legacy attrs
-        reg.inc("prefix.hits")
-        reg.inc("prefix.hit_tokens", best.depth)
+        _obs_registry().inc("prefix.hits")
         for n in self._chain(best):
             n.last_used = now
         return best, best.depth
@@ -141,13 +140,11 @@ class PrefixCache:
                 child = _Node(node, key, int(block_ids[i]), (i + 1) * bs)
                 pool.incref([child.block_id])
                 node.children[key] = child
+                self._n_nodes += 1
             child.last_used = now
             chain.append(child.block_id)
             node = child
-        reg = _obs_registry()
-        if reg.enabled:
-            reg.inc("prefix.inserts")
-            reg.set("prefix.nodes", self.n_nodes)
+        _obs_registry().set("prefix.nodes", self._n_nodes)
         return chain, (None if node is self.root else node)
 
     def attach_snapshot(self, node: Optional[_Node], snapshot) -> None:
@@ -166,10 +163,10 @@ class PrefixCache:
         pool.decref([victim.block_id])
         victim.parent.children.pop(victim.tokens, None)
         victim.snapshot = None
+        self._n_nodes -= 1
         reg = _obs_registry()
-        if reg.enabled:
-            reg.inc("prefix.evictions")
-            reg.set("prefix.nodes", self.n_nodes)
+        reg.inc("prefix.evictions")
+        reg.set("prefix.nodes", self._n_nodes)
         return True
 
     def _iter_nodes(self):
@@ -181,4 +178,5 @@ class PrefixCache:
 
     @property
     def n_nodes(self) -> int:
-        return sum(1 for _ in self._iter_nodes())
+        """Nodes in the trie, counted as they are inserted and evicted."""
+        return self._n_nodes

@@ -71,6 +71,7 @@ from __future__ import annotations
 
 import copy
 import dataclasses
+import functools
 import time
 from collections import OrderedDict
 from typing import List, Optional
@@ -105,6 +106,19 @@ class _Request:
     t_arrival: float = 0.0       # tracer clock at ORIGINAL submit — unlike
     tenant: str = ""             # t_submit it survives preemption, so TTFT
     ttft: Optional[float] = None  # stays arrival-based (§12)
+    prefill_s: Optional[float] = None  # admission to first token
+
+
+def _sched_span(name: str):
+    """Record every call of the decorated method as span ``name`` on the
+    ``sched`` track (DESIGN §11)."""
+    def wrap(fn):
+        @functools.wraps(fn)
+        def spanned(self, *args, **kwargs):
+            with obs.tracer().span(name, track="sched"):
+                return fn(self, *args, **kwargs)
+        return spanned
+    return wrap
 
 
 def _cache_leaves(caches):
@@ -344,7 +358,7 @@ class Scheduler:
         self.records[r.rid] = {
             "rid": r.rid, "tenant": r.tenant, "outcome": outcome,
             "t_arrival": r.t_arrival, "queue_delay_s": queue_delay,
-            "ttft_s": r.ttft, "tpot_s": tpot,
+            "ttft_s": r.ttft, "tpot_s": tpot, "prefill_s": r.prefill_s,
             "new_tokens": len(r.generated)}
         while len(self.records) > RECORDS_KEEP:
             self.records.popitem(last=False)
@@ -382,14 +396,18 @@ class Scheduler:
 
     # ------------------------------------------------------------- helpers
     def _alloc_dense(self, n: int):
-        """All-or-nothing dense alloc, LRU-evicting prefix entries first."""
-        while True:
-            ids = self.dense_pool.alloc(n)
-            if ids is not None:
-                return ids
-            if self.prefix is None or not self.prefix.evict_lru(
-                    self.dense_pool):
-                return None
+        """All-or-nothing dense alloc, LRU-evicting prefix entries until it
+        fits (one ``evict`` span per allocation that had to evict)."""
+        ids = self.dense_pool.alloc(n)
+        if ids is not None or self.prefix is None:
+            return ids
+        with obs.tracer().span("evict", track="sched") as span:
+            leaves = 0
+            while ids is None and self.prefix.evict_lru(self.dense_pool):
+                leaves += 1
+                ids = self.dense_pool.alloc(n)
+            span["leaves"] = leaves
+        return ids
 
     def _window_blocks_for(self, tokens: int) -> int:
         """Ring blocks needed once ``tokens`` tokens have been written:
@@ -415,6 +433,7 @@ class Scheduler:
         self.caches = self.server.restore_row(
             self.caches, copy.deepcopy(self._empty_row), jnp.int32(b))
 
+    @_sched_span("finish")
     def _finish(self, b):
         s = self._slots[b]
         r = s["req"]
@@ -491,6 +510,7 @@ class Scheduler:
         return False
 
     # ------------------------------------------------------------ admission
+    @_sched_span("admit")
     def _admit(self, b, r: _Request) -> Optional[bool]:
         """Admit ``r`` into row ``b``: allocate its blocks, restore its
         snapshot/tables, and park it in ``phase="prefill"`` — the prompt
@@ -612,13 +632,17 @@ class Scheduler:
             segs.append((b, s["done"], take))
             budget -= take
 
-        for b, _, _ in segs:                 # resume paused rows
-            s = self._slots[b]
-            if s["paused_snap"] is not None:
-                self.caches = srv.restore_row(self.caches, s["paused_snap"],
-                                              jnp.int32(b))
-                s["paused_snap"] = None
-                obs.registry().inc("serve.resumes")
+        reg, tr = obs.registry(), obs.tracer()
+        paused = [b for b, _, _ in segs
+                  if self._slots[b]["paused_snap"] is not None]
+        if paused:
+            with tr.span("resume", track="sched", rows=len(paused)):
+                for b in paused:
+                    s = self._slots[b]
+                    self.caches = srv.restore_row(
+                        self.caches, s["paused_snap"], jnp.int32(b))
+                    s["paused_snap"] = None
+                    reg.inc("serve.resumes")
 
         N = self.max_segs
         buf = np.zeros((C,), np.int32)
@@ -634,17 +658,14 @@ class Scheduler:
             off += take
             cu[i + 1] = off
         cu[len(segs) + 1:] = off
-        reg, tr = obs.registry(), obs.tracer()
         with tr.span("prefill_chunk", track="sched", segs=len(segs),
-                     tokens=off):
+                     tokens=off, segs_rows_past_take=list(segs)):
             logits, self.caches = srv.prefill_packed(
                 srv.params, jnp.asarray(buf)[None], self.caches,
                 jnp.asarray(cu), jnp.asarray(rows), jnp.asarray(past))
         self.stats["prefilled_tokens"] += off
         self.stats["prefill_chunks"] += 1
         self.stats["prefill_chunk_slots"] += C
-        reg.inc("serve.prefill_chunks")
-        reg.inc("serve.prefilled_tokens", off)
         reg.observe("serve.chunk_packed_efficiency", off / C,
                     bounds=obs.UNIT_BOUNDS)
 
@@ -653,7 +674,9 @@ class Scheduler:
             s["done"] += take
             if s["insert_at"] is not None and s["done"] == s["insert_at"]:
                 self._insert_prefix(b)
-            if s["done"] == len(s["prompt_np"]):
+            if s["done"] != len(s["prompt_np"]):
+                continue
+            with tr.span("first_token", track="sched", row=b):
                 s["phase"] = "decode"
                 s["length"] = len(s["prompt_np"])
                 key, sub = jax.random.split(key)
@@ -673,6 +696,7 @@ class Scheduler:
                     # time, queue wait included — under load the queue IS
                     # the latency.  Survives preemption via t_arrival.
                     self._record_ttft(r, now - r.t_arrival)
+                    r.prefill_s = now - s["t_admit"]
                 self._sample_router_health(b)
                 cur = cur.at[b, 0].set(int(tok0[0]))
                 if len(r.generated) >= r.max_new or int(tok0[0]) == self.eos:
@@ -692,13 +716,15 @@ class Scheduler:
         self._health_seen += 1
         if (self._health_seen - 1) % self.router_health_every:
             return
-        s = self._slots[b]
-        snap = jax.device_get(
-            self.server.snapshot_row(self.caches, jnp.int32(b)))
-        stats = _router_health_from_snapshot(snap, len(s["prompt_np"]))
-        for k, v in stats.items():
-            reg.observe(f"serve.router.{k}", v, bounds=obs.UNIT_BOUNDS)
+        with obs.tracer().span("router_health", track="sched"):
+            s = self._slots[b]
+            snap = jax.device_get(
+                self.server.snapshot_row(self.caches, jnp.int32(b)))
+            stats = _router_health_from_snapshot(snap, len(s["prompt_np"]))
+            for k, v in stats.items():
+                reg.observe(f"serve.router.{k}", v, bounds=obs.UNIT_BOUNDS)
 
+    @_sched_span("prefix_insert")
     def _insert_prefix(self, b):
         """Insert row ``b``'s shareable prefix into the trie.  Called when
         ``done`` hits the forced boundary: the row's device state is then
@@ -724,15 +750,20 @@ class Scheduler:
         lengths) makes the decode writes drop.  ``_advance_prefills``
         restores the snapshot before the row's next chunk."""
         srv = self.server
-        for b, s in enumerate(self._slots):
-            if s is not None and s["phase"] == "prefill" \
-                    and s["paused_snap"] is None:
-                s["paused_snap"] = jax.device_get(
+        rows = [b for b, s in enumerate(self._slots)
+                if s is not None and s["phase"] == "prefill"
+                and s["paused_snap"] is None]
+        if not rows:
+            return
+        reg = obs.registry()
+        with obs.tracer().span("pause_prefills", track="sched", rows=rows):
+            for b in rows:
+                self._slots[b]["paused_snap"] = jax.device_get(
                     srv.snapshot_row(self.caches, jnp.int32(b)))
                 self.caches = srv.restore_row(
                     self.caches, copy.deepcopy(self._empty_row),
                     jnp.int32(b))
-                obs.registry().inc("serve.pauses")
+                reg.inc("serve.pauses")
 
     # ------------------------------------------------------------- growth
     def _alloc_or_preempt(self, alloc_fn, n: int, b: int, live):
@@ -831,7 +862,9 @@ class Scheduler:
                     wait = source.next_arrival_in(
                         obs.tracer().now() - t_run0)
                     if wait is not None and wait > 0:
-                        time.sleep(min(wait, 0.05))
+                        with obs.tracer().span("wait_arrival",
+                                               track="sched"):
+                            time.sleep(min(wait, 0.05))
                     continue
                 for b in range(B):
                     if self._slots[b] is None and self.queue \
@@ -880,40 +913,43 @@ class Scheduler:
                 # latest-admitted rows (mid-prefill ones included — their
                 # full prompt just requeues) when a pool runs dry.
                 self._pause_prefills()
-                live = [b for b in range(B) if self._slots[b] is not None]
-                for b in sorted(live_dec,
-                                key=lambda x: self._slots[x]["seq"]):
-                    if self._slots[b] is None:
-                        continue
-                    self._grow_row(b, n, live)
+                reg, tr = obs.registry(), obs.tracer()
+                with tr.span("grow_rows", track="sched"):
+                    live = [b for b in range(B)
+                            if self._slots[b] is not None]
+                    for b in sorted(live_dec,
+                                    key=lambda x: self._slots[x]["seq"]):
+                        if self._slots[b] is None:
+                            continue
+                        self._grow_row(b, n, live)
                 live_dec = by_phase("decode")
                 if not live_dec:
                     continue
 
                 key, sub = jax.random.split(key)
-                reg, tr = obs.registry(), obs.tracer()
                 with tr.span("decode_chunk", track="sched",
-                             rows=len(live_dec), n=n):
+                             rows=len(live_dec), n=n,
+                             lengths=[self._slots[b]["length"]
+                                      for b in live_dec]):
                     toks, self.caches = srv.decode_many(srv.params, cur,
                                                         self.caches, sub, n)
                     steps += n
                     host = jax.device_get(toks)
-                reg.inc("serve.decode_chunks")
                 reg.inc("serve.decode_tokens", n * len(live_dec))
-                reg.observe("serve.decode_batch", len(live_dec))
                 cur = toks[:, -1:]
-                for b in live_dec:
-                    s = self._slots[b]
-                    if s is None:
-                        continue
-                    r = s["req"]
-                    for t in host[b]:
-                        r.generated.append(int(t))
-                        s["length"] += 1
-                        if int(t) == self.eos or \
-                                len(r.generated) >= r.max_new:
-                            self._finish(b)
-                            break
+                with tr.span("emit", track="sched"):
+                    for b in live_dec:
+                        s = self._slots[b]
+                        if s is None:
+                            continue
+                        r = s["req"]
+                        for t in host[b]:
+                            r.generated.append(int(t))
+                            s["length"] += 1
+                            if int(t) == self.eos or \
+                                    len(r.generated) >= r.max_new:
+                                self._finish(b)
+                                break
         timer.__exit__(None, None, None)
         reg = obs.registry()
         if reg.enabled:

@@ -18,6 +18,7 @@ from repro.launch.serve import Scheduler, Server
 from repro.obs.export import prometheus_text
 from repro.obs.metrics import (DEFAULT_BOUNDS, UNIT_BOUNDS, Histogram,
                                Registry, publish)
+from repro.obs.slo import records_from_spans
 from repro.obs.tracing import Tracer
 from repro.serve.paged_kv import PagedConfig
 from tests._property_harness import given, settings, st
@@ -367,6 +368,161 @@ def test_scheduler_artifacts_and_lifecycle(tmp_path):
     assert g["pool.dense.live_high_water"] > 0
     assert any(k.startswith("prefix.") for k in snap["counters"])
     assert g["serve.tokens_per_s"] > 0
+
+
+SCHED_SPANS = {"admit", "evict", "resume", "first_token", "prefix_insert",
+               "router_health", "pause_prefills", "grow_rows", "emit",
+               "finish", "wait_arrival", "prefill_chunk", "decode_chunk"}
+
+
+class _Later:
+    """A timed source: three requests at once, the rest once the
+    scheduler has drained them and slept for ``pause`` seconds."""
+
+    def __init__(self, prompts, max_new, pause=0.05):
+        self.first, self.rest = prompts[:3], prompts[3:]
+        self.max_new, self.pause, self.due = max_new, pause, None
+
+    def pump(self, sched, now):
+        for p in self.first:
+            sched.submit(p, self.max_new)
+        self.first = []
+        if self.due is not None and now >= self.due:
+            for p in self.rest:
+                sched.submit(p, self.max_new)
+            self.rest = []
+
+    def exhausted(self):
+        return not self.first and not self.rest
+
+    def next_arrival_in(self, now):
+        if self.due is None:
+            self.due = now + self.pause
+        return max(self.due - now, 0.0)
+
+
+def _span_run(enabled):
+    """The hybrid on a pool that the prefix trie fills, prompts longer
+    than a prefill chunk beside decoding rows, and a pause between
+    arrivals: every scheduler span has a reason to open.  Records the
+    scheduler's own decode lengths and packed segments at dispatch."""
+    cfg = _hybrid_cfg()
+    server = Server(cfg, batch=2, max_len=64,
+                    paged=PagedConfig(block_size=8, num_blocks=12,
+                                      num_window_blocks=4))
+    sched = Scheduler(server, chunk=4, chunk_tokens=16, prefix_cache=True,
+                      router_health_every=1)
+    decode_lengths, prefill_segs = [], []
+    inner_decode, inner_prefill = server.decode_many, server.prefill_packed
+
+    def decode_many(params, tok, caches, key, n):
+        decode_lengths.append([s["length"] for s in sched._slots
+                               if s is not None and s["phase"] == "decode"])
+        return inner_decode(params, tok, caches, key, n)
+
+    def prefill_packed(params, tokens, caches, cu, rows, past):
+        cu, rows, past = (np.asarray(a) for a in (cu, rows, past))
+        prefill_segs.append([(int(rows[i]), int(past[i]),
+                              int(cu[i + 1] - cu[i]))
+                             for i in range(len(rows))
+                             if rows[i] >= 0 and cu[i + 1] > cu[i]])
+        return inner_prefill(params, tokens, caches, cu, rows, past)
+
+    server.decode_many, server.prefill_packed = decode_many, prefill_packed
+    rng = np.random.default_rng(3)
+    prompts = [rng.integers(2, cfg.vocab, size=(n,)).astype(np.int32)
+               for n in (5, 40, 33, 21, 38, 27)]
+    obs.set_enabled(enabled)
+    sched.run(source=_Later(prompts, max_new=6))
+    return sched, decode_lengths, prefill_segs
+
+
+@pytest.mark.parametrize("enabled", [True, False])
+def test_scheduler_spans_args_and_prefill_s(enabled):
+    """Every scheduler span lands on the ``sched`` track under its name;
+    ``decode_chunk``'s ``lengths`` and ``prefill_chunk``'s
+    ``segs_rows_past_take`` equal the scheduler's own state at dispatch;
+    every finished record carries ``prefill_s`` (admission to first
+    token, inside TTFT).  With obs off the ring and the registry stay
+    empty and the records are still written."""
+    sched, decode_lengths, prefill_segs = _span_run(enabled)
+    recs = list(sched.records.values())
+    assert len(recs) == 6
+    for r in recs:
+        assert r["outcome"] == "finished"
+        assert 0 < r["prefill_s"] <= r["ttft_s"] - r["queue_delay_s"] + 1e-9
+    if not enabled:
+        assert len(obs.tracer()) == 0
+        assert obs.registry().snapshot() == \
+            {"counters": {}, "gauges": {}, "gauges_meta": {},
+             "histograms": {}}
+        return
+    spans = [s for s in obs.tracer().spans() if s.track == "sched"]
+    assert {s.name for s in spans} == SCHED_SPANS
+    dec = [s for s in spans if s.name == "decode_chunk"]
+    assert [s.args["lengths"] for s in dec] == decode_lengths
+    assert all(s.args["rows"] == len(s.args["lengths"]) for s in dec)
+    pre = [s for s in spans if s.name == "prefill_chunk"]
+    assert [[tuple(x) for x in s.args["segs_rows_past_take"]]
+            for s in pre] == prefill_segs
+    assert all(s.args["leaves"] > 0 for s in spans if s.name == "evict")
+    assert all(s.args["rows"] for s in spans if s.name == "pause_prefills")
+    by_rid = {r["rid"]: r for r in records_from_spans(obs.tracer().spans())}
+    for rid, r in sched.records.items():
+        assert by_rid[rid]["prefill_s"] == r["prefill_s"]
+
+
+def _decode_many_hlo(server, caches):
+    tok = jnp.zeros((server.batch, 1), jnp.int32)
+    return server._decode_many.lower(
+        server.params, tok, caches, jax.random.PRNGKey(0), 4,
+        jnp.float32(0.0), 0, False).compile().as_text()
+
+
+def test_mosa_scopes_reach_compiled_decode_op_names(monkeypatch):
+    """The MoSA stored-set re-sort and its gathers carry ``mosa.upkeep`` in
+    their ``op_name`` inside the compiled ``decode_many`` (a ``lax.scan``
+    body), routing carries ``mosa.route``; and the scopes change nothing
+    but metadata: with ``jax.named_scope`` made inert the compiled program
+    is the same text once metadata is stripped."""
+    import contextlib
+    import re
+    cfg = dataclasses.replace(
+        get_config("mosa-paper", preset="smoke", variant="mosa", sparsity=4),
+        n_layers=2, pattern=(BlockSpec("attn", "dense"),
+                             BlockSpec("mosa", "dense")))
+    server = Server(cfg, batch=2, max_len=64,
+                    paged=PagedConfig(block_size=8, num_blocks=16))
+    caches = Scheduler(server).caches
+    text = _decode_many_hlo(server, caches)
+
+    def op_names(kind):
+        return [m.group(1) for m in re.finditer(
+            rf' {kind}\(.*op_name="([^"]*)"', text)]
+
+    sorts, gathers = op_names("sort"), op_names("gather")
+    assert sorts and all("/mosa.upkeep/" in n for n in sorts), sorts
+    assert sum("/mosa.upkeep/" in n for n in gathers) >= 4, gathers
+    assert all("/while/body/" in n for n in sorts + gathers)
+    assert re.search(r'op_name="[^"]*/mosa\.route/', text)
+    assert re.search(r'op_name="[^"]*/mosa\.attend/', text)
+
+    monkeypatch.setattr(jax, "named_scope",
+                        lambda name: contextlib.nullcontext())
+    jax.clear_caches()                    # trace again, without scopes
+    plain = _decode_many_hlo(server, caches)
+    jax.clear_caches()
+
+    def canon(t):
+        # the computations alone (not the source-location tables), less
+        # each instruction's metadata, with instructions renamed in order
+        # of appearance (the numbering of names may shift)
+        t = re.sub(r", metadata=\{[^}]*\}", "", t[t.index("\n%"):])
+        names = {}
+        return re.sub(r"%[\w.\-]+", lambda m: names.setdefault(
+            m.group(0), f"%v{len(names)}"), t)
+    assert "mosa.upkeep" not in plain
+    assert canon(plain) == canon(text)
 
 
 # ------------------------------------------- device-metrics / train side

@@ -311,3 +311,31 @@ def test_prefix_trie_insert_lookup_refcounts():
     while trie.evict_lru(pool):
         pass
     assert trie.n_nodes == 0 and pool.free_blocks == 16
+
+
+@settings(max_examples=20, deadline=None)
+@given(ops=st.lists(st.integers(0, 9), min_size=1, max_size=40),
+       seed=st.integers(0, 2 ** 16))
+def test_prefix_trie_node_count_matches_walk(ops, seed):
+    """``n_nodes`` is a count kept by ``insert`` and ``evict_lru``: after
+    any sequence of inserts (shared and fresh prefixes of random depth)
+    and LRU evictions it equals a full walk of the trie, and the
+    ``prefix.nodes`` gauge reads the same."""
+    from repro import obs
+    obs.registry().reset()
+    rng = random.Random(seed)
+    pool = BlockPool(64, 2)
+    trie = PrefixCache(2)
+    for op in ops:
+        if op < 6:
+            n = rng.randint(1, 4)
+            toks = [rng.randint(0, 2) for _ in range(2 * n)]
+            ids = pool.alloc(n)
+            if ids is None:
+                continue
+            trie.insert(toks, ids, pool)
+            pool.decref(ids)                # the row retires
+        else:
+            trie.evict_lru(pool)
+        assert trie.n_nodes == sum(1 for _ in trie._iter_nodes())
+        assert obs.registry().gauge("prefix.nodes").value == trie.n_nodes

@@ -528,7 +528,7 @@ def test_scheduler_records_match_span_records():
         # every other field is computed from the same floats — exact.
         assert got["ttft_s"] == pytest.approx(want["ttft_s"], rel=1e-9)
         for k in ("tenant", "outcome", "t_arrival", "queue_delay_s",
-                  "tpot_s", "new_tokens"):
+                  "tpot_s", "prefill_s", "new_tokens"):
             assert got[k] == want[k], (rid, k, got[k], want[k])
     ev = evaluate(list(live.values()), SLOSpec(ttft_s=1e9))
     assert ev["finished"] == 3 and "per_tenant" in ev
