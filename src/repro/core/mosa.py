@@ -51,6 +51,41 @@ def _per_head(x, w, cd):
     return y.swapaxes(0, 1).astype(cd)
 
 
+def insert_sorted(cache: MoSAKVCache, selected, slot, k_new, v_new, score,
+                  pos):
+    """Write one decoded token into each head's stored set, keeping the set
+    sorted by position with empty slots last (DESIGN §5).
+
+    ``selected`` / ``slot`` (B, H) are ``streaming_topk_update``'s verdict,
+    ``k_new`` / ``v_new`` (B, H, d), ``score`` (B, H) and ``pos`` (B, H) the
+    token's key, value, router score and position.  Returns the new
+    ``(k, v, scores, idx)``.
+
+    No sort is needed: the set is sorted with empties last, ``argmin`` takes
+    the first empty slot while the set is not full, and ``pos`` is larger
+    than every stored position.  So a set that is not full takes the token
+    at ``slot`` and nothing moves; a full set drops ``slot``, shifts slots
+    ``slot+1 .. k-1`` down by one and takes the token at ``k-1``.  Each array
+    is read once and written once by an elementwise select between itself
+    and its one-slot shift — the same values, in the same order, that a
+    re-sort by position would give."""
+    kc = cache.idx.shape[-1]
+    j = jax.lax.broadcasted_iota(jnp.int32, cache.idx.shape, 2)  # (B,H,k)
+    last = jnp.where(cache.idx[..., -1] >= 0, kc - 1, slot)[..., None]
+    sel = selected[..., None]
+    shift = sel & (slot[..., None] <= j) & (j < last)
+    put = sel & (j == last)
+
+    def move(a, new, shift, put):
+        down = jnp.concatenate([a[:, :, 1:], a[:, :, -1:]], 2)
+        return jnp.where(put, new[:, :, None], jnp.where(shift, down, a))
+
+    return (move(cache.k, k_new, shift[..., None], put[..., None]),
+            move(cache.v, v_new, shift[..., None], put[..., None]),
+            move(cache.scores, score, shift, put),
+            move(cache.idx, pos.astype(cache.idx.dtype), shift, put))
+
+
 @dataclasses.dataclass(frozen=True)
 class MoSAAttention:
     d_model: int
@@ -1039,11 +1074,12 @@ class MoSAAttention:
         KV memory stays at k entries per head forever.
 
         Positions are per-row (``cache.length``): under continuous batching
-        rows sit at different sequence offsets.  After every insertion the
-        cache slots are re-sorted by original position (empty slots last), so
-        ``cache.idx`` keeps the ascending-index invariant that training-time
-        ``select_topk`` establishes — the layout stays deterministic and any
-        index-derived causal mask stays lower-triangular (DESIGN §5).
+        rows sit at different sequence offsets.  ``insert_sorted`` places the
+        token so the slots stay sorted by original position (empty slots
+        last): ``cache.idx`` keeps the ascending-index invariant that
+        training-time ``select_topk`` establishes — the layout stays
+        deterministic and any index-derived causal mask stays
+        lower-triangular (DESIGN §5).
         """
         if self.cfg.selection_granularity == "block":
             return self._decode_block(params, x, cache, positions)
@@ -1070,22 +1106,16 @@ class MoSAAttention:
                                  self.rotary_frac)[:, :, 0]
 
         with jax.named_scope(UPKEEP):
-            selected, slot, new_scores, new_idx = streaming_topk_update(
-                cache.scores, cache.idx, score,
-                jnp.broadcast_to(t[:, None], (B, H)), is_forced)
-
-            onehot = jax.nn.one_hot(slot, cache.k.shape[2], dtype=cd)
-            upd = (onehot * selected[..., None].astype(cd))[..., None]
-            new_k = cache.k * (1 - upd) + upd * kk[:, :, None]
-            new_v = cache.v * (1 - upd) + upd * v[:, :, None]
-
-            # Restore the sorted-ascending slot order (empties sort last).
-            order = jnp.argsort(jnp.where(
-                new_idx < 0, jnp.iinfo(jnp.int32).max, new_idx), -1)
-            new_idx = jnp.take_along_axis(new_idx, order, -1)
-            new_scores = jnp.take_along_axis(new_scores, order, -1)
-            new_k = jnp.take_along_axis(new_k, order[..., None], 2)
-            new_v = jnp.take_along_axis(new_v, order[..., None], 2)
+            pos = jnp.broadcast_to(t[:, None], (B, H))
+            selected, slot, _, _ = streaming_topk_update(
+                cache.scores, cache.idx, score, pos, is_forced)
+            # The barrier keeps the insertion out of the attention's fusion:
+            # the attention then reads the stored set from memory, as it
+            # read the output of a re-sort, and its compiled sums keep their
+            # order.  Fused, they summed in another order on a TPU v5e and
+            # greedy tokens drifted from a re-sort's.
+            new_k, new_v, new_scores, new_idx = jax.lax.optimization_barrier(
+                insert_sorted(cache, selected, slot, kk, v, score, pos))
 
         # Attention of the (possibly inserted) query over the cached set.
         with jax.named_scope(ATTEND):

@@ -24,8 +24,8 @@ from repro.nn.layers import _trunc_normal
 
 # ``jax.named_scope`` names of MoSA's compiled operations: they reach each
 # operation's ``op_name`` metadata, so a device trace can attribute time to
-# routing, to stored-set upkeep (top-k, slot writes, re-sort and its
-# gathers) and to attention over the stored set.
+# routing, to stored-set upkeep (top-k and the sorted insertion by a
+# one-slot shift) and to attention over the stored set.
 ROUTE, UPKEEP, ATTEND = "mosa.route", "mosa.upkeep", "mosa.attend"
 
 
@@ -178,7 +178,7 @@ def streaming_topk_update(cache_scores, cache_idx, new_score, new_pos, is_forced
       slot:     (...,) int  — which slot it replaced (valid where selected)
     """
     min_slot = jnp.argmin(cache_scores, axis=-1)                   # (...,)
-    min_score = jnp.take_along_axis(cache_scores, min_slot[..., None], -1)[..., 0]
+    min_score = jnp.min(cache_scores, axis=-1)
     selected = jnp.logical_or(new_score > min_score, is_forced)
     slot = min_slot
     new_scores = jnp.where(

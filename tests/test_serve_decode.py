@@ -2,6 +2,7 @@
 continuous batching, and DESIGN.md reference integrity (PR 2)."""
 
 import re
+import zlib
 from pathlib import Path
 
 import jax
@@ -11,7 +12,9 @@ import pytest
 
 from repro.configs.base import MoSAConfig, get_config
 from repro.core.kv_cache import DenseKVCache, MoSAKVCache, WindowKVCache
-from repro.core.mosa import MoSAAttention
+import repro.core.mosa as mosa_mod
+from repro.core.mosa import MoSAAttention, insert_sorted
+from repro.core.router import streaming_topk_update
 from repro.dist import sharding as shd
 from repro.launch.mesh import make_host_mesh
 from repro.nn.transformer import TransformerLM
@@ -160,6 +163,205 @@ def test_mosa_decode_per_row_positions():
                                np.asarray(y_solo, np.float32),
                                atol=1e-5, rtol=1e-5)
     assert int(joint2.length[0]) == 9 and int(joint2.length[1]) == 4
+
+
+# Sort-and-gather oracle of the stored-set upkeep: evict-min with the minimum
+# read by a gather, a one-hot blend of the new K/V, then a re-sort of every
+# array by position (empties last).  ``insert_sorted`` must match it bit for
+# bit while running no sort and no gather.
+def _oracle_topk_update(cache_scores, cache_idx, new_score, new_pos,
+                        is_forced):
+    min_slot = jnp.argmin(cache_scores, axis=-1)
+    min_score = jnp.take_along_axis(cache_scores, min_slot[..., None],
+                                    -1)[..., 0]
+    selected = jnp.logical_or(new_score > min_score, is_forced)
+    hit = (jax.nn.one_hot(min_slot, cache_scores.shape[-1], dtype=bool)
+           & selected[..., None])
+    new_scores = jnp.where(hit, new_score[..., None], cache_scores)
+    new_idx = jnp.where(
+        hit, jnp.asarray(new_pos)[..., None].astype(cache_idx.dtype),
+        cache_idx)
+    return selected, min_slot, new_scores, new_idx
+
+
+def _oracle_insert(cache, selected, slot, k_new, v_new, score, pos):
+    # ``slot`` is the oracle's argmin; the evict-min writes of scores and
+    # positions are ``_oracle_topk_update``'s, repeated on the old set.
+    cd = k_new.dtype
+    hit = (jax.nn.one_hot(slot, cache.idx.shape[-1], dtype=bool)
+           & selected[..., None])
+    new_scores = jnp.where(hit, score[..., None], cache.scores)
+    new_idx = jnp.where(hit, pos[..., None].astype(cache.idx.dtype),
+                        cache.idx)
+    onehot = jax.nn.one_hot(slot, cache.k.shape[2], dtype=cd)
+    upd = (onehot * selected[..., None].astype(cd))[..., None]
+    new_k = cache.k * (1 - upd) + upd * k_new[:, :, None]
+    new_v = cache.v * (1 - upd) + upd * v_new[:, :, None]
+    order = jnp.argsort(jnp.where(
+        new_idx < 0, jnp.iinfo(jnp.int32).max, new_idx), -1)
+    return (jnp.take_along_axis(new_k, order[..., None], 2),
+            jnp.take_along_axis(new_v, order[..., None], 2),
+            jnp.take_along_axis(new_scores, order, -1),
+            jnp.take_along_axis(new_idx, order, -1))
+
+
+def _sorted_state(rng, B, H, K, d, n_valid, t, dtype):
+    """A stored set as decode finds it: per (row, head) ``n_valid`` distinct
+    positions below ``t`` in ascending order, then empty slots."""
+    idx = np.full((B, H, K), -1, np.int32)
+    scores = np.full((B, H, K), -np.inf, np.float32)
+    for b in range(B):
+        for h in range(H):
+            n = int(n_valid[b, h])
+            idx[b, h, :n] = np.sort(rng.choice(t, n, replace=False))
+            scores[b, h, :n] = rng.uniform(0.05, 0.95, n)
+    k = rng.standard_normal((B, H, K, d)).astype(np.float32)
+    v = rng.standard_normal((B, H, K, d)).astype(np.float32)
+    return MoSAKVCache(jnp.asarray(k, dtype), jnp.asarray(v, dtype),
+                       jnp.asarray(scores), jnp.asarray(idx),
+                       jnp.full((B,), t, jnp.int32))
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32])
+@pytest.mark.parametrize("case", ["empty", "forced_t0", "partial", "full",
+                                  "not_selected", "tied_min", "mixed"])
+def test_mosa_insert_sorted_matches_sort_gather_oracle(case, dtype):
+    """The one-slot-shift insertion gives the oracle's slots, values and
+    order: empty, partly filled and full sets, the forced first token,
+    rejected tokens and tied minimum scores (argmin takes the first)."""
+    seed = zlib.crc32(f"{case}-{dtype.__name__}".encode())
+    rng = np.random.default_rng(seed)
+    B, H, K, d = 4, 6, 16, 8
+    t = 0 if case == "forced_t0" else 200
+    n_valid = {"empty": 0, "forced_t0": 0, "full": K, "not_selected": K,
+               "tied_min": K}.get(case)
+    if n_valid is None:
+        hi = K if case == "partial" else K + 1
+        n_valid = rng.integers(0 if case == "mixed" else 1, hi, (B, H))
+    n_valid = np.broadcast_to(n_valid, (B, H))
+    cache = _sorted_state(rng, B, H, K, d, n_valid, max(t, K), dtype)
+    score = rng.uniform(0.05, 0.95, (B, H)).astype(np.float32)
+    if case == "not_selected":
+        score = np.asarray(cache.scores).min(-1) * 0.5
+    if case == "tied_min":
+        s = np.asarray(cache.scores).copy()
+        for b in range(B):
+            for h in range(H):
+                s[b, h, rng.choice(K, 3, replace=False)] = 0.01
+        cache = cache._replace(scores=jnp.asarray(s))
+    if case == "mixed":
+        score = np.where(rng.random((B, H)) < 0.3, 0.0, score)
+    score = jnp.asarray(score, jnp.float32)
+    pos = jnp.full((B, H), t, jnp.int32)
+    forced = jnp.asarray(case == "forced_t0")
+    k_new = jnp.asarray(rng.standard_normal((B, H, d)), dtype)
+    v_new = jnp.asarray(rng.standard_normal((B, H, d)), dtype)
+
+    sel, slot, _, _ = streaming_topk_update(cache.scores, cache.idx, score,
+                                            pos, forced)
+    got = insert_sorted(cache, sel, slot, k_new, v_new, score, pos)
+    osel, oslot, _, _ = _oracle_topk_update(cache.scores, cache.idx, score,
+                                            pos, forced)
+    want = _oracle_insert(cache, osel, oslot, k_new, v_new, score, pos)
+    np.testing.assert_array_equal(np.asarray(sel), np.asarray(osel))
+    np.testing.assert_array_equal(np.asarray(slot), np.asarray(oslot))
+    for name, g, w in zip(("k", "v", "scores", "idx"), got, want):
+        assert g.dtype == w.dtype, name
+        np.testing.assert_array_equal(np.asarray(g, np.float32),
+                                      np.asarray(w, np.float32), err_msg=name)
+    if case == "not_selected":
+        assert not np.asarray(sel).any()
+    if case == "tied_min":
+        assert (np.asarray(np.asarray(cache.scores)[
+            np.arange(B)[:, None], np.arange(H), np.asarray(slot)])
+            == 0.01).all()
+    if case == "forced_t0":
+        assert np.asarray(sel).all()
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32])
+@pytest.mark.parametrize("P", [0, 3])
+def test_mosa_decode_step_matches_sort_gather_oracle(P, dtype, monkeypatch):
+    """48 decode steps with k_fixed < T, from an empty set (the forced first
+    token, then filling) or after a short prefill: every output and every
+    stored set equals the sort-and-gather step's, and the sets stay valid,
+    unique, ascending and empties-last."""
+    K, G = 6, 48
+    cfg = MoSAConfig(n_mosa_heads=3, sparsity=4, n_dense_heads=0, d_head=8,
+                     k_fixed=K)
+    layer = MoSAAttention(64, cfg, compute_dtype=dtype)
+    key = jax.random.PRNGKey(3)
+    params = layer.init(key)
+    B = 2
+    x = jax.random.normal(key, (B, P + G, 64), jnp.float32)
+    cache = MoSAKVCache.create(B, cfg.n_mosa_heads, K, cfg.d_head, dtype)
+    if P:
+        _, cache = layer.prefill(params, x[:, :P], cache)
+
+    def run(c0):
+        # A fresh function per run: jit traces whichever upkeep is patched in.
+        step = jax.jit(lambda p, xt, c: layer.decode_step(p, xt, c))
+        c, ys, cs = c0, [], []
+        for t in range(P, P + G):
+            y, c = step(params, x[:, t:t + 1], c)
+            ys.append(y)
+            cs.append(c)
+        return ys, cs
+
+    ys, cs = run(cache)
+    with monkeypatch.context() as m:
+        m.setattr(mosa_mod, "streaming_topk_update", _oracle_topk_update)
+        m.setattr(mosa_mod, "insert_sorted", _oracle_insert)
+        oys, ocs = run(cache)
+    for t, (y, oy, c, oc) in enumerate(zip(ys, oys, cs, ocs), P):
+        np.testing.assert_array_equal(np.asarray(y, np.float32),
+                                      np.asarray(oy, np.float32),
+                                      err_msg=f"y at t={t}")
+        for name in ("k", "v", "scores", "idx"):
+            np.testing.assert_array_equal(
+                np.asarray(getattr(c, name), np.float32),
+                np.asarray(getattr(oc, name), np.float32),
+                err_msg=f"{name} at t={t}")
+        idx = np.asarray(c.idx)
+        for row in idx.reshape(-1, K):
+            valid = row[row >= 0]
+            assert (valid <= t).all() and (np.diff(valid) > 0).all()
+            assert (row[len(valid):] == -1).all()
+    assert (np.asarray(cs[-1].idx) >= 0).all()      # the sets filled
+
+
+def test_mosa_decode_upkeep_has_no_sort_or_gather():
+    """Token-choice decode keeps its stored set sorted without a sort
+    primitive and without a gather over the (B, H, k, d) K/V or the
+    (B, H, k) scores and positions."""
+    B, H, K, d = 2, 3, 5, 8
+    cfg = MoSAConfig(n_mosa_heads=H, sparsity=4, n_dense_heads=0, d_head=d,
+                     k_fixed=K)
+    layer = MoSAAttention(64, cfg)
+    params = layer.init(jax.random.PRNGKey(0))
+    cache = MoSAKVCache.create(B, H, K, d, jnp.float32)
+    x = jnp.ones((B, 1, 64), jnp.float32)
+    jaxpr = jax.make_jaxpr(layer.decode_step)(params, x, cache).jaxpr
+
+    def eqns(jx):
+        for e in jx.eqns:
+            yield e
+            for p in e.params.values():
+                for sub in (p if isinstance(p, (list, tuple)) else [p]):
+                    if isinstance(sub, jax.extend.core.ClosedJaxpr):
+                        yield from eqns(sub.jaxpr)
+                    elif isinstance(sub, jax.extend.core.Jaxpr):
+                        yield from eqns(sub)
+
+    prims = [(e.primitive.name,
+              tuple(e.invars[0].aval.shape) if e.invars else ())
+             for e in eqns(jaxpr)]
+    assert any(name == "argmin" for name, _ in prims)   # the walk sees upkeep
+    # the stored set reaches the attention through a barrier (see decode_step)
+    assert any(name == "optimization_barrier" for name, _ in prims)
+    assert not [p for p in prims if p[0] == "sort"]
+    stored = {(B, H, K, d), (B, H, K)}
+    assert not [p for p in prims if p[0] == "gather" and p[1] in stored]
 
 
 def test_window_decode_parity_past_window():
